@@ -17,8 +17,9 @@ per-bench parsers.  ``mesh`` is the layout tag: ``"1"`` for
 single-device, ``"R"`` for a 1D theta mesh, ``"RxC"`` for a 2D
 theta x vertex mesh (`mesh_tag` derives it from a ``jax.sharding.Mesh``).
 ``git_sha`` is the commit the numbers were measured at and
-``device_kind`` the platform they were measured on (``cpu``/``tpu``/
-``gpu``) — committed BENCH files are only comparable when both match.
+``device_kind`` the device they were measured on, as JAX names it
+(``cpu``, ``TPU v5 lite``) — committed BENCH files are only comparable
+when both match.
 
 Two *optional* cross-bench keys exist beyond the extras free-for-all
 (PR 10): ``impl`` — which kernel implementation actually ran
@@ -96,12 +97,10 @@ def snapshot_scalar(snapshot: dict, name: str, default: float = 0.0):
 
 
 def device_kind() -> str:
-    """Accelerator platform of device 0 (``cpu``/``gpu``/``tpu``)."""
-    try:
-        import jax
-        return jax.devices()[0].platform
-    except Exception:
-        return "unknown"
+    """``device_kind`` of device 0 as JAX reports it (``"TPU v5 lite"``,
+    ``"cpu"``) — the key `repro.launch.roofline.HW_PEAKS` uses."""
+    import jax
+    return jax.devices()[0].device_kind
 
 
 def mesh_tag(mesh) -> str:

@@ -43,7 +43,7 @@ from benchmarks._util import block, print_table, timeit
 from repro.core.engine import IMMConfig, InfluenceEngine
 from repro.graphs import rmat_graph
 from repro.kernels import ops as kops
-from repro.launch.roofline import achieved_frac
+from repro.launch.roofline import HW_PEAKS, achieved_frac
 
 # small n + many batches on purpose: the fused chain removes per-batch
 # dispatch + the (B, n) handoff, which is exactly the regime where that
@@ -103,6 +103,9 @@ def run(n, m, theta, batch, seed, k, mesh=None, log=print):
     # what the dispatch layer would pick for the single-device commit
     # kernel here; sharded cells use the jnp oracle inside shard_map
     impl = "oracle" if mesh is not None else kops.resolve_impl()
+    # a device metric only where the device's peaks are published: a CPU
+    # run writes none
+    on_device = device_kind() in HW_PEAKS
     rows, bench = [], []
     for store in STORES:
         kind = "packed" if store == "packed" else "bitmap"
@@ -115,18 +118,21 @@ def run(n, m, theta, batch, seed, k, mesh=None, log=print):
         sel = _assert_bitwise(off, on, k)
         speedup = w_off / w_on if w_on > 0 else 0.0
         for fused, wall in ((False, w_off), (True, w_on)):
-            af = achieved_frac("sample_write_count", wall / batches,
-                               B=batch, n=n, kind=kind)
             extra = dict(kernel="sample_write_count", fused=fused,
-                         store=store, impl=impl,
-                         achieved_frac=round(af, 6))
+                         store=store, impl=impl)
+            af = None
+            if on_device:
+                af = achieved_frac("sample_write_count", wall / batches,
+                                   B=batch, n=n, kind=kind)
+                extra["achieved_frac"] = round(af, 6)
             if fused:
                 extra["speedup"] = round(speedup, 3)
             bench.append(bench_row(
                 f"kernel_pipeline/{store}/"
                 f"{'fused' if fused else 'unfused'}",
                 n=n, theta=theta, wall_s=wall, mesh=mesh, **extra))
-            rows.append([store, fused, f"{wall:.3f}", impl, f"{af:.4f}",
+            rows.append([store, fused, f"{wall:.3f}", impl,
+                         "not measured" if af is None else f"{af:.4f}",
                          f"{speedup:.2f}x" if fused else "-"])
         log(f"[kernel-pipeline] store={store}: unfused {w_off:.3f}s, "
             f"fused {w_on:.3f}s ({speedup:.2f}x), influence "
@@ -140,33 +146,30 @@ def run(n, m, theta, batch, seed, k, mesh=None, log=print):
 
 
 def run_hw(n, batch, seed, log=print):
-    """Raw arena-commit kernel, pallas vs oracle, on real hardware only.
+    """Raw arena-commit kernel on a device with published peaks only.
 
     The interpreter is not hardware — timing it says nothing about the
     MXU path — so off-accelerator this section skips cleanly."""
     dk = device_kind()
-    if dk not in ("tpu", "gpu"):
+    if dk not in HW_PEAKS:
         log(f"[kernel-pipeline] device_kind={dk}: skipping the raw "
-            "arena_commit hardware section (needs tpu/gpu)")
+            "arena_commit hardware section (needs a device in HW_PEAKS)")
         return []
     import jax
     rng = np.random.default_rng(seed)
     rows_np = (rng.random((batch, n)) < 0.25).astype(np.uint8)
     bench = []
     for kind in ("bitmap", "packed"):
-        for use_pallas in (False, True):
-            fn = jax.jit(lambda r, up=use_pallas, kd=kind: kops.arena_commit(
-                r, kind=kd, use_pallas=up))
-            wall = timeit(fn, jax.numpy.asarray(rows_np))
-            impl = "pallas" if use_pallas else "oracle"
-            bench.append(bench_row(
-                f"arena_commit/{kind}/{impl}", n=n, theta=batch,
-                wall_s=wall, kernel="arena_commit", fused=False,
-                store=kind, impl=impl,
-                achieved_frac=round(achieved_frac(
-                    "arena_commit", wall, B=batch, n=n, kind=kind), 6)))
-            log(f"[kernel-pipeline] arena_commit {kind}/{impl}: "
-                f"{wall * 1e3:.3f}ms")
+        fn = jax.jit(lambda r, kd=kind: kops.arena_commit(r, kind=kd))
+        wall = timeit(fn, jax.numpy.asarray(rows_np))
+        bench.append(bench_row(
+            f"arena_commit/{kind}/pallas", n=n, theta=batch,
+            wall_s=wall, kernel="arena_commit", fused=False,
+            store=kind, impl="pallas",
+            achieved_frac=round(achieved_frac(
+                "arena_commit", wall, B=batch, n=n, kind=kind), 6)))
+        log(f"[kernel-pipeline] arena_commit {kind}/pallas: "
+            f"{wall * 1e3:.3f}ms")
     return bench
 
 

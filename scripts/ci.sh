@@ -100,23 +100,22 @@ XLA_FLAGS="--xla_force_host_platform_device_count=4${XLA_FLAGS:+ $XLA_FLAGS}" \
         --out "${TMPDIR:-/tmp}/BENCH_10.json"
 
 # fused-pipeline schema gate: every BENCH_10 row must carry the kernel /
-# fused / impl / achieved_frac fields the roofline layer reports, and the
-# optional-key validation in benchmarks/_emit.py must have let them pass
+# fused / impl fields, and achieved_frac only where the device has
+# published peaks (a CPU run writes none)
 python - "${TMPDIR:-/tmp}/BENCH_10.json" <<'PY'
 import json, sys
 rows = json.load(open(sys.argv[1]))
 assert rows, "BENCH_10.json has no rows"
 for row in rows:
-    missing = [k for k in ("kernel", "fused", "impl", "achieved_frac")
-               if k not in row]
+    missing = [k for k in ("kernel", "fused", "impl") if k not in row]
     assert not missing, f"row {row.get('name')} missing {missing}"
     assert row["impl"] in ("pallas", "interpret", "oracle"), row
-    assert 0.0 <= row["achieved_frac"] <= 1.0, row
+    assert ("achieved_frac" in row) == (row["device_kind"] != "cpu"), row
+    assert 0.0 <= row.get("achieved_frac", 0.0) <= 1.0, row
 fused = [r for r in rows if r.get("fused")]
 assert fused and all("speedup" in r for r in fused), \
     "fused rows must report speedup vs the unfused twin"
-print(f"BENCH_10 schema OK: {len(rows)} rows carry "
-      f"kernel/fused/impl/achieved_frac")
+print(f"BENCH_10 schema OK: {len(rows)} rows carry kernel/fused/impl")
 PY
 
 # streaming benchmark smoke (tiny evolving graph; the non-slow analogue of

@@ -1,25 +1,16 @@
-"""Version-compat shims for the jax API surface this repo relies on.
+"""The one ``shard_map`` spelling this repo uses.
 
-``shard_map`` moved from ``jax.experimental.shard_map`` (kwarg
-``check_rep``) to top-level ``jax.shard_map`` (kwarg ``check_vma``); this
-repo supports both so the sharded selection/MoE/GNN paths run on the
-container's pinned jax as well as newer releases.
+Every sharded kernel (stores, selection, the fused chain, the MoE/GNN
+paths) calls `shard_map` from here, so the replication-check policy is
+set in one place: off, because the per-tile bodies psum and all-gather
+by hand and their outputs' replication is established by construction.
 """
 from __future__ import annotations
 
 import jax
 
-try:
-    _shard_map_new = jax.shard_map          # newer jax: top-level API
-except AttributeError:
-    _shard_map_new = None
-    from jax.experimental.shard_map import shard_map as _shard_map_old
-
 
 def shard_map(f, *, mesh, in_specs, out_specs):
-    """`jax.shard_map` with replication checking off, on any jax version."""
-    if _shard_map_new is not None:
-        return _shard_map_new(f, mesh=mesh, in_specs=in_specs,
-                              out_specs=out_specs, check_vma=False)
-    return _shard_map_old(f, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs, check_rep=False)
+    """`jax.shard_map` with replication checking off."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)

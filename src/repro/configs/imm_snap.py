@@ -53,10 +53,11 @@ def make_theta_mesh(shards=None, *, axis: str = THETA_AXIS):
     if hasattr(shards, "shape"):        # already a Mesh
         return shards
     import jax
+    from repro.launch.mesh import make_mesh
 
     avail = jax.device_count()
     n = avail if shards == "auto" else min(int(shards), avail)
-    return jax.make_mesh((n,), (axis,))
+    return make_mesh((n,), (axis,))
 
 
 def make_im_mesh(spec=None, *, theta_axis: str = THETA_AXIS,
@@ -85,11 +86,12 @@ def make_im_mesh(spec=None, *, theta_axis: str = THETA_AXIS,
     if dt < 1 or dv < 1:
         raise ValueError(f"mesh shape {dt}x{dv} must be >= 1x1")
     import jax
+    from repro.launch.mesh import make_mesh
 
     avail = jax.device_count()
     dt = max(min(dt, avail), 1)             # theta sharding survives...
     dv = max(min(dv, avail // dt), 1)       # ...the vertex axis shrinks
-    return jax.make_mesh((dt, dv), (theta_axis, vertex_axis))
+    return make_mesh((dt, dv), (theta_axis, vertex_axis))
 
 
 def mesh_engine_kwargs(mesh) -> dict:

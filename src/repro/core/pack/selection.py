@@ -1,9 +1,10 @@
 """Greedy max-coverage directly over encoded arenas.
 
-`select_packed` decodes the bit-packed arena once inside jit and runs
-the identical `select_dense` body — the decoded bits are a fusion
-temporary, the at-rest arena stays 8x smaller.  `select_compressed`
-never materializes the decoded arena at all: each greedy round rebuilds
+`select_packed` never decodes the bit-packed arena: each greedy round
+rebuilds the counter with the decode-and-count kernel
+(``kernels/ops.packed_count``) through `repro.core.selection.select_fused`,
+so the arena stays 8x smaller at every step.  `select_compressed`
+never materializes the decoded arena either: each greedy round rebuilds
 the counter with the decode-and-count kernel (``kernels/ops.token_count``
 — Pallas on TPU, jnp oracle elsewhere, ``interpret=True`` validates the
 kernel on CPU) and tests the winner's membership by token comparison.
@@ -21,8 +22,8 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
-from repro.core.pack.codec import token_decode_cols, unpack_bits
-from repro.core.selection import register_selection, select_dense
+from repro.core.pack.codec import codec_for, token_decode_cols
+from repro.core.selection import register_selection, select_fused
 from repro.kernels import ops
 
 
@@ -32,13 +33,14 @@ def select_packed(Rp, valid, n: int, k: int, method: str = "rebuild"):
     bool.  Returns (seeds (k,) int32, covered_frac () f32,
     gains (k,) int32) — bitwise-equal to ``select_dense`` on the
     unpacked rows."""
-    return select_dense(unpack_bits(Rp, n), valid, k, method)
+    return select_fused(Rp, valid, n, k, method,
+                        codec=codec_for("packed", n))
 
 
 @partial(jax.jit,
-         static_argnames=("n", "k", "method", "use_pallas", "interpret"))
+         static_argnames=("n", "k", "method", "interpret"))
 def select_compressed(T, valid, n: int, k: int, method: str = "rebuild",
-                      *, use_pallas=None, interpret: bool = False):
+                      *, interpret: bool = False):
     """T: (theta, s_pad) int32 token rows (``repro.core.pack.codec``
     format); valid: (theta,) bool.  Greedy selection whose per-round
     counter comes from the decode-and-count kernel — the decoded
@@ -48,7 +50,7 @@ def select_compressed(T, valid, n: int, k: int, method: str = "rebuild",
     def counter_of(alive):
         return ops.token_count(
             T, alive.astype(jnp.float32), n=n,
-            use_pallas=use_pallas, interpret=interpret).astype(jnp.float32)
+            interpret=interpret).astype(jnp.float32)
 
     def member(v):
         return token_decode_cols(T, v.reshape(1))[:, 0]

@@ -119,9 +119,8 @@ class CodecStore(_ArenaBase):
     def _row_contrib(self, mask):
         # decode-and-count through the kernels/ops dispatch (jnp oracle
         # off-TPU, Pallas on TPU) — exact: integer counts in f32
-        if self.codec.kind == "packed":
-            return ops.packed_count(self.R, mask, n=self.n)
-        return ops.token_count(self.R, mask, n=self.n)
+        return ops.arena_count(self.R, mask,
+                               codec=self.codec).astype(jnp.int32)
 
     def _compress_step(self) -> bool:
         ladder = self.policy.ladder if self.policy is not None else ()

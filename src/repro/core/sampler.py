@@ -135,25 +135,6 @@ def _shard_cols(x, placement):
         x, NamedSharding(placement.mesh, spec))
 
 
-def _pin_replicated(x, placement):
-    """Pin a freshly drawn threefry array to the replicated layout under
-    a 2D placement.  The container's jax runs the *non-partitionable*
-    threefry (``jax_threefry_partitionable=False``), whose generator
-    GSPMD may lower differently per sharding context — an unpinned
-    ``uniform``/``randint`` inside a vertex-sharded computation produces
-    *different values* than the single-device trace, silently breaking
-    the layout-independent key stream.  Replicating the draw (generation
-    is redundant per device; the masked traversal compute downstream
-    stays partitioned) restores the historical stream bitwise.  The
-    identity-keyed stable coins never hit this: they are elementwise
-    counter-mode hashes of (key, row, vertex/edge id), which partition
-    cleanly over both mesh axes with no pin."""
-    if _vertex_axis_of(placement) is None:
-        return x
-    return jax.lax.with_sharding_constraint(
-        x, NamedSharding(placement.mesh, PartitionSpec()))
-
-
 # ---------------------------------------------------------------- models ----
 #
 # A DiffusionModel owns *semantics only*: how an edge (or a visited
@@ -310,8 +291,7 @@ def _setup(key, batch, n_nodes, positions, placement, stable):
     ``positions`` (stable only) gathers a row subset of the full batch.
     """
     kroot, kstep = jax.random.split(key)
-    roots_full = _pin_replicated(
-        jax.random.randint(kroot, (batch,), 0, n_nodes), placement)
+    roots_full = jax.random.randint(kroot, (batch,), 0, n_nodes)
     if not stable:
         if positions is not None:
             raise ValueError(
@@ -397,8 +377,7 @@ def _dense_loop(key, logq, positions=None, *, batch: int, max_steps: int = 0,
             kd = jnp.asarray(sub, jnp.uint32).reshape(-1)
             coin = _u01(_mix32(_mix32(uids ^ kd[0]) ^ bb ^ kd[1]))
         else:
-            coin = _pin_replicated(
-                jax.random.uniform(sub, frontier.shape), placement)
+            coin = jax.random.uniform(sub, frontier.shape)
         if kernel:
             new = kops.ic_frontier_step(
                 frontier, visited, logq, coin,
@@ -467,9 +446,7 @@ def _sparse_loop(key, edge_src, edge_dst, edge_prob, positions=None, *,
             coin = _u01(_mix32(_mix32(uid ^ kd[0]) ^ bb ^ kd[1]))
             hit = coin < edge_prob[None, :]
         else:
-            hit = _pin_replicated(
-                jax.random.uniform(sub, (batch, m)),
-                placement) < edge_prob[None, :]
+            hit = jax.random.uniform(sub, (batch, m)) < edge_prob[None, :]
         # reverse traversal: edge u->v is usable when v is in the frontier
         live = frontier[:, edge_dst] & hit & ~visited[:, edge_src]
         # scatter-or into src — the segment_max counter-update pattern (C1)
@@ -541,8 +518,7 @@ def _walk_loop(key, dst_offsets, in_src, in_cum, in_total, positions=None, *,
             kd = jnp.asarray(sub, jnp.uint32).reshape(-1)
             r = _u01(_mix32(_mix32(brow ^ kd[0]) ^ kd[1]))
         else:
-            r = _pin_replicated(jax.random.uniform(sub, (batch,)),
-                                placement)
+            r = jax.random.uniform(sub, (batch,))
         total = in_total[cur]
         go = jnp.logical_and(active, r < total)
         nxt = jax.vmap(pick_in_neighbor)(cur, r)

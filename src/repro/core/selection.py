@@ -61,45 +61,39 @@ def select_dense(R, valid, k: int, method: str = "rebuild"):
     """R: (theta, n) uint8 bitmaps; valid: (theta,) bool (generated sets).
 
     Single-device (arrays replicated / unsharded); ``valid`` may be any
-    mask.  Returns (seeds (k,) int32, covered_frac () f32,
-    gains (k,) int32).
+    mask.  Every counter rebuild streams the uint8 arena tile by tile
+    through `repro.kernels.ops.coverage_matvec` (Pallas on TPU, the jnp
+    oracle elsewhere), so no widened copy of the arena is ever made.
+    Returns (seeds (k,) int32, covered_frac () f32, gains (k,) int32).
     """
-    theta, n = R.shape
-    Rf = R.astype(jnp.float32)
-    alive0 = valid
-
-    def rebuild_round(alive):
-        counter = alive.astype(jnp.float32) @ Rf            # (n,)
-        v = jnp.argmax(counter).astype(jnp.int32)
-        covered = (R[:, v] > 0) & alive
-        gain = covered.sum(dtype=jnp.int32)
-        return v, gain, alive & ~covered, counter
+    def counter_of(rows):
+        return kops.coverage_matvec(rows.astype(jnp.float32), R)
 
     if method == "rebuild":
         def body(i, state):
             alive, seeds, gains = state
-            v, gain, alive, _ = rebuild_round(alive)
-            return alive, seeds.at[i].set(v), gains.at[i].set(gain)
+            v = jnp.argmax(counter_of(alive)).astype(jnp.int32)
+            covered = (R[:, v] > 0) & alive
+            gain = covered.sum(dtype=jnp.int32)
+            return alive & ~covered, seeds.at[i].set(v), gains.at[i].set(gain)
 
         alive, seeds, gains = jax.lax.fori_loop(
             0, k, body,
-            (alive0, jnp.zeros((k,), jnp.int32), jnp.zeros((k,), jnp.int32)),
+            (valid, jnp.zeros((k,), jnp.int32), jnp.zeros((k,), jnp.int32)),
         )
     elif method == "decrement":
-        counter0 = alive0.astype(jnp.float32) @ Rf
-
         def body(i, state):
             alive, counter, seeds, gains = state
             v = jnp.argmax(counter).astype(jnp.int32)
             covered = (R[:, v] > 0) & alive
             gain = covered.sum(dtype=jnp.int32)
-            counter = counter - covered.astype(jnp.float32) @ Rf
+            counter = counter - counter_of(covered)
             return (alive & ~covered, counter,
                     seeds.at[i].set(v), gains.at[i].set(gain))
 
         alive, _, seeds, gains = jax.lax.fori_loop(
             0, k, body,
-            (alive0, counter0, jnp.zeros((k,), jnp.int32),
+            (valid, counter_of(valid), jnp.zeros((k,), jnp.int32),
              jnp.zeros((k,), jnp.int32)),
         )
     else:
@@ -229,7 +223,8 @@ def _starts_for(mesh, vertex_axis, n, partition):
 def select_dense_sharded(mesh, R, valid, k: int, *,
                          theta_axes=("data",), vertex_axis=None,
                          method: str = "rebuild", n: int | None = None,
-                         partition=None, codec=None):
+                         partition=None, codec=None,
+                         interpret: bool = False):
     """EfficientIMM selection with the theta axis sharded over ``theta_axes``
     (paper C1) and, optionally, the vertex axis over ``vertex_axis``.
 
@@ -257,6 +252,13 @@ def select_dense_sharded(mesh, R, valid, k: int, *,
     The greedy argmax is computed redundantly on every device (cheap,
     avoids a broadcast).
 
+    Every per-tile reduction runs through the `repro.kernels.ops`
+    dispatch: bitmap tiles reduce with `coverage_matvec`, packed and
+    compressed tiles with the decode-and-count kernels (``interpret=True``
+    runs them through the Pallas interpreter), so no tile is ever widened
+    or whole-tile decoded; membership of the winner is a one-column read
+    (``decode_cols`` on encoded tiles).
+
     ``method="rebuild"`` re-reduces the surviving local rows every round
     (C5).  ``method="decrement"`` is the true decremental update executed
     tile-locally: each device keeps a partial counter over its own rows
@@ -271,29 +273,29 @@ def select_dense_sharded(mesh, R, valid, k: int, *,
     if method not in ("rebuild", "decrement"):
         raise ValueError(f"unknown method {method}")
     starts_arr = _starts_for(mesh, vertex_axis, n, partition)
+    kind = "bitmap" if codec is None else codec.kind
 
-    def local_select(R_enc, valid_local, starts=None):
-        # IMPack arenas rest encoded: decode each device's tile inside
-        # shard_map (a jit temporary — the decoded tile never lands in
-        # HBM between rounds) and run the identical greedy body, so
-        # selections are bitwise-equal to the bitmap layout
-        R_local = (R_enc if codec is None or codec.kind == "bitmap"
-                   else codec.decode(R_enc))
-        Rf = R_local.astype(jnp.float32)
+    def local_select(R_local, valid_local, starts=None):
+        def partial_of(alive):
+            return kops.arena_count(R_local, alive, codec=codec,
+                                    interpret=interpret)
+
+        def member_local(lv):
+            if kind == "bitmap":
+                return R_local[:, lv] > 0
+            return codec.decode_cols(R_local, lv.reshape(1))[:, 0]
 
         def pick(counter, alive):
-            """Greedy argmax over the global counter -> (v, covered)."""
             if vertex_axis is not None:
                 return _vertex_sharded_pick(
-                    counter, alive, n, vertex_axis,
-                    lambda lv: R_local[:, lv] > 0, starts)
+                    counter, alive, n, vertex_axis, member_local, starts)
             v = jnp.argmax(counter).astype(jnp.int32)
-            return v, (R_local[:, v] > 0) & alive
+            return v, member_local(v) & alive
 
         if method == "rebuild":
             def body(i, state):
                 alive, seeds, gains = state
-                counter = jax.lax.psum(alive.astype(jnp.float32) @ Rf, axes)
+                counter = jax.lax.psum(partial_of(alive), axes)
                 v, covered = pick(counter, alive)
                 gain = jax.lax.psum(covered.sum(dtype=jnp.int32), axes)
                 return (alive & ~covered,
@@ -305,21 +307,19 @@ def select_dense_sharded(mesh, R, valid, k: int, *,
                  jnp.zeros((k,), jnp.int32)),
             )
         else:
-            partial0 = valid_local.astype(jnp.float32) @ Rf
-
             def body(i, state):
                 alive, partial, seeds, gains = state
                 counter = jax.lax.psum(partial, axes)
                 v, covered = pick(counter, alive)
                 gain = jax.lax.psum(covered.sum(dtype=jnp.int32), axes)
-                partial = partial - covered.astype(jnp.float32) @ Rf
+                partial = partial - partial_of(covered)
                 return (alive & ~covered, partial,
                         seeds.at[i].set(v), gains.at[i].set(gain))
 
             alive, _, seeds, gains = jax.lax.fori_loop(
                 0, k, body,
-                (valid_local, partial0, jnp.zeros((k,), jnp.int32),
-                 jnp.zeros((k,), jnp.int32)),
+                (valid_local, partial_of(valid_local),
+                 jnp.zeros((k,), jnp.int32), jnp.zeros((k,), jnp.int32)),
             )
         n_valid = jnp.maximum(
             jax.lax.psum(valid_local.sum(dtype=jnp.float32), axes), 1.0)
@@ -465,14 +465,7 @@ def select_fused(R, valid, n: int, k: int, method: str = "rebuild", *,
     kind = "bitmap" if codec is None else codec.kind
 
     def counter_of(alive):
-        a = alive.astype(jnp.float32)
-        if kind == "bitmap":
-            return kops.coverage_matvec(a, R, interpret=interpret)
-        if kind == "packed":
-            return kops.packed_count(
-                R, a, n=n, interpret=interpret).astype(jnp.float32)
-        return kops.token_count(
-            R, a, n=n, interpret=interpret).astype(jnp.float32)
+        return kops.arena_count(R, alive, codec=codec, interpret=interpret)
 
     def member(v):
         if kind == "bitmap":
@@ -514,101 +507,6 @@ def select_fused(R, valid, n: int, k: int, method: str = "rebuild", *,
 
     n_valid = jnp.maximum(valid.sum(dtype=jnp.float32), 1.0)
     return seeds, gains.sum(dtype=jnp.float32) / n_valid, gains
-
-
-def select_fused_sharded(mesh, R, valid, k: int, *,
-                         theta_axes=("data",), vertex_axis=None,
-                         method: str = "rebuild", n: int | None = None,
-                         partition=None, codec=None,
-                         interpret: bool = False):
-    """`select_dense_sharded` with every per-tile reduction routed
-    through the `repro.kernels.ops` dispatch: bitmap tiles reduce with
-    `coverage_matvec`, packed/compressed tiles with the decode-and-count
-    kernels — so encoded tiles are *never* whole-tile decoded, per round
-    or otherwise (membership of the winner is a one-column
-    ``decode_cols``).  Pad-column masking, balanced-partition offsets and
-    the argmax tie-break all go through the shared
-    `_vertex_sharded_pick`, so selections are bitwise-identical to the
-    unfused sharded strategies (and to the single-device ones) on any
-    mesh and either column layout.
-    """
-    axes = tuple(theta_axes)
-    if method not in ("rebuild", "decrement"):
-        raise ValueError(f"unknown method {method}")
-    starts_arr = _starts_for(mesh, vertex_axis, n, partition)
-    kind = "bitmap" if codec is None else codec.kind
-    n_tile = None if codec is None else codec.n_cols
-
-    def local_select(R_local, valid_local, starts=None):
-        def partial_of(alive):
-            a = alive.astype(jnp.float32)
-            if kind == "bitmap":
-                return kops.coverage_matvec(a, R_local, interpret=interpret)
-            if kind == "packed":
-                return kops.packed_count(
-                    R_local, a, n=n_tile,
-                    interpret=interpret).astype(jnp.float32)
-            return kops.token_count(
-                R_local, a, n=n_tile,
-                interpret=interpret).astype(jnp.float32)
-
-        def member_local(lv):
-            if kind == "bitmap":
-                return R_local[:, lv] > 0
-            return codec.decode_cols(R_local, lv.reshape(1))[:, 0]
-
-        def pick(counter, alive):
-            if vertex_axis is not None:
-                return _vertex_sharded_pick(
-                    counter, alive, n, vertex_axis, member_local, starts)
-            v = jnp.argmax(counter).astype(jnp.int32)
-            return v, member_local(v) & alive
-
-        if method == "rebuild":
-            def body(i, state):
-                alive, seeds, gains = state
-                counter = jax.lax.psum(partial_of(alive), axes)
-                v, covered = pick(counter, alive)
-                gain = jax.lax.psum(covered.sum(dtype=jnp.int32), axes)
-                return (alive & ~covered,
-                        seeds.at[i].set(v), gains.at[i].set(gain))
-
-            alive, seeds, gains = jax.lax.fori_loop(
-                0, k, body,
-                (valid_local, jnp.zeros((k,), jnp.int32),
-                 jnp.zeros((k,), jnp.int32)),
-            )
-        else:
-            def body(i, state):
-                alive, partial, seeds, gains = state
-                counter = jax.lax.psum(partial, axes)
-                v, covered = pick(counter, alive)
-                gain = jax.lax.psum(covered.sum(dtype=jnp.int32), axes)
-                partial = partial - partial_of(covered)
-                return (alive & ~covered, partial,
-                        seeds.at[i].set(v), gains.at[i].set(gain))
-
-            alive, _, seeds, gains = jax.lax.fori_loop(
-                0, k, body,
-                (valid_local, partial_of(valid_local),
-                 jnp.zeros((k,), jnp.int32), jnp.zeros((k,), jnp.int32)),
-            )
-        n_valid = jnp.maximum(
-            jax.lax.psum(valid_local.sum(dtype=jnp.float32), axes), 1.0)
-        return seeds, gains.sum(dtype=jnp.float32) / n_valid, gains
-
-    out_specs = (P(), P(), P())
-    if starts_arr is None:
-        fn = shard_map(
-            local_select, mesh=mesh,
-            in_specs=(P(axes, vertex_axis), P(axes)), out_specs=out_specs,
-        )
-        return fn(R, valid)
-    fn = shard_map(
-        local_select, mesh=mesh,
-        in_specs=(P(axes, vertex_axis), P(axes), P()), out_specs=out_specs,
-    )
-    return fn(R, valid, starts_arr)
 
 
 def greedy_select(R_or_idx, valid, k: int, *, n: int | None = None,
@@ -711,7 +609,7 @@ def _fused_sharded_strategy(method):
             partition=None, codec=None, pallas_interpret=False, **_):
         if mesh is None:
             raise ValueError("sharded selection needs a mesh")
-        return select_fused_sharded(
+        return select_dense_sharded(
             mesh, view.R, view.valid, k,
             theta_axes=theta_axes, vertex_axis=vertex_axis, method=method,
             n=view.n, partition=partition, codec=codec,

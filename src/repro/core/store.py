@@ -73,6 +73,7 @@ from repro import obs
 from repro.compat import shard_map
 from repro.core.adaptive import bitmap_to_indices
 from repro.graphs.partition import VertexPartition, vertex_partition
+from repro.kernels import ops as kops
 
 MIN_CAPACITY = 16     # matches the historical pad floor (1 << 4)
 MIN_INDEX_PAD = 4     # matches the historical l_pad floor (1 << 2)
@@ -528,9 +529,8 @@ class BitmapStore(_ArenaBase):
 
     def _row_contrib(self, mask):
         """Fused-counter contribution of the masked rows (exact: counts
-        fit f32 integers)."""
-        return (mask.astype(jnp.float32)
-                @ self.R.astype(jnp.float32)).astype(jnp.int32)
+        fit f32 integers), read tile-wise by the counting kernel."""
+        return kops.arena_count(self.R, mask).astype(jnp.int32)
 
     def add_batch(self, visited, counter=None) -> np.ndarray:
         """Append ``visited (B, n) uint8`` rows in place.
@@ -971,8 +971,7 @@ def _sharded_stream_kernels(mesh, theta_axes, vertex_axis, codec=None):
     sp_rows, sp_vec = P(theta_axes, vertex_axis), P(theta_axes)
 
     def kill(R, counter, sizes, live, dead):
-        bits = R if codec is None else codec.decode(R)
-        contrib = dead.astype(jnp.float32) @ bits.astype(jnp.float32)
+        contrib = kops.arena_count(R, dead, codec=codec)
         counter = counter - contrib.astype(jnp.int32)[None, :]
         return counter, jnp.where(dead, 0, sizes), live & ~dead
 

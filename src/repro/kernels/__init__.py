@@ -4,7 +4,8 @@
 ref.py             pure-jnp oracles (CPU + dry-run execution path)
 ops.py             jit'd dispatch wrappers (backend auto-detect)
 
-Validated in interpret mode against ref.py (tests/test_kernels.py).
+Semantics checked in interpret mode against ref.py (tests/test_kernels.py);
+the IM kernels compiled for a TPU v5e by tests/test_tpu_compile.py.
 """
 from repro.kernels import ops, ref
 from repro.kernels.ops import (

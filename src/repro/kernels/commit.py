@@ -31,7 +31,7 @@ from repro.kernels import _pad
 
 DEFAULT_TILE_ROWS = 128
 DEFAULT_TILE_N = 512
-DEFAULT_TILE_BYTES = 64
+DEFAULT_TILE_BYTES = 128
 
 
 def _bitmap_kernel(rows_ref, stored_ref, colsum_ref):
@@ -57,15 +57,16 @@ def _packed_kernel(rows_ref, stored_ref, colsum_ref):
     jj = jax.lax.broadcasted_iota(jnp.int32, (tw8, tw), 1)
     weights = jnp.where(cc // 8 == jj,
                         jnp.left_shift(1, cc % 8), 0).astype(jnp.float32)
-    packed = jnp.dot(rows.astype(jnp.float32), weights,
+    ri = rows.astype(jnp.int32)
+    packed = jnp.dot(ri.astype(jnp.float32), weights,
                      preferred_element_type=jnp.float32)
-    stored_ref[...] = packed.astype(jnp.uint8)
+    stored_ref[...] = packed.astype(jnp.int32).astype(jnp.uint8)
 
     @pl.when(r == 0)
     def _init():
         colsum_ref[...] = jnp.zeros_like(colsum_ref)
 
-    colsum_ref[...] += rows.astype(jnp.int32).sum(axis=0, keepdims=True)
+    colsum_ref[...] += ri.sum(axis=0, keepdims=True)
 
 
 @functools.partial(

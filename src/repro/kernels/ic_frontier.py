@@ -3,12 +3,17 @@
 The beyond-paper MXU formulation (DESIGN §2): the probability that vertex u
 is activated by the current frontier is 1 - prod_{v in F}(1 - p), so one BFS
 step is ``new = (rand < 1 - exp(frontier @ logq)) & ~visited`` — a matmul in
-the log-semiring fused with Bernoulli sampling and the visited-bitmap mask
+the log-semiring followed by Bernoulli sampling and the visited-bitmap mask
 (the paper's hottest data structure, Alg. 3 line 8).
 
-Grid: (B/Tb, n/Tn, n/Tk) with the contraction axis minor; the logits
-accumulate in VMEM scratch and the sampling epilogue fires on the last k
-tile, so the (B, n) logit matrix never materializes in HBM.
+Grid: (B/Tb, n/Tn, n/Tk) with the contraction axis minor; the log-survival
+accumulates in VMEM scratch and is written once on the last k tile.  The
+sampling epilogue (``-expm1``, compare, mask) runs in the jnp wrapper:
+Mosaic has no ``expm1`` lowering, and the epilogue must stay bitwise the
+oracle's (`repro.kernels.ref.ic_frontier_ref`) so the ``pallas`` backend
+draws the same sets as ``dense``.  Block shapes on v5e: frontier
+(Tb, Tk) uint8, logq (Tk, Tn) f32, out (Tb, Tn) f32 with Tb = 128 and
+Tn = Tk = 512; ``tests/test_tpu_compile.py`` compiles it at n = 4096.
 
 On a 2D (theta x vertex) mesh this kernel runs inside the dense loop's
 double-buffered frontier dispatch (``core/sampler.py::_dense_loop`` with
@@ -30,7 +35,7 @@ from jax.experimental.pallas import tpu as pltpu
 from repro.kernels import _pad
 
 
-def _kernel(front_ref, logq_ref, rand_ref, visited_ref, out_ref, acc_ref):
+def _kernel(front_ref, logq_ref, out_ref, acc_ref):
     kk = pl.program_id(2)
     nk = pl.num_programs(2)
 
@@ -38,15 +43,13 @@ def _kernel(front_ref, logq_ref, rand_ref, visited_ref, out_ref, acc_ref):
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    f = front_ref[...].astype(jnp.float32)          # (Tb, Tk)
-    q = logq_ref[...]                               # (Tk, Tn)
-    acc_ref[...] += jnp.dot(f, q, preferred_element_type=jnp.float32)
+    f = front_ref[...].astype(jnp.int32).astype(jnp.float32)    # (Tb, Tk)
+    acc_ref[...] += jnp.dot(f, logq_ref[...],
+                            preferred_element_type=jnp.float32)
 
     @pl.when(kk == nk - 1)
-    def _sample():
-        p_act = -jnp.expm1(acc_ref[...])            # 1 - exp(acc)
-        new = (rand_ref[...] < p_act) & (visited_ref[...] == 0)
-        out_ref[...] = new.astype(jnp.uint8)
+    def _done():
+        out_ref[...] = acc_ref[...]
 
 
 @functools.partial(
@@ -61,25 +64,24 @@ def ic_frontier_step(frontier, visited, logq, rand, *, tile_b: int = 128,
     """
     B, n = frontier.shape
     tb, tn, tk = min(tile_b, B), min(tile_n, n), min(tile_k, n)
-    # neutral-element padding: frontier 0 (no contribution), visited 1
-    # (suppresses activation in padded columns), rand 1 (coin never fires)
+    # zero padding is neutral for the log-survival sum: a padded frontier
+    # column contributes nothing, padded logq columns are sliced off
     fp = _pad.pad_to(_pad.pad_to(frontier.astype(jnp.uint8), 0, tb), 1, tk)
     lp = _pad.pad_to(_pad.pad_to(logq, 0, tk), 1, tn)
-    rp = _pad.pad_to(_pad.pad_to(rand, 0, tb, 1.0), 1, tn, 1.0)
-    vp = _pad.pad_to(_pad.pad_to(visited.astype(jnp.uint8), 0, tb, 1), 1, tn, 1)
     grid = (pl.cdiv(B, tb), pl.cdiv(n, tn), pl.cdiv(n, tk))
-    out = pl.pallas_call(
+    acc = pl.pallas_call(
         _kernel,
         grid=grid,
         in_specs=[
             pl.BlockSpec((tb, tk), lambda b, i, k: (b, k)),
             pl.BlockSpec((tk, tn), lambda b, i, k: (k, i)),
-            pl.BlockSpec((tb, tn), lambda b, i, k: (b, i)),
-            pl.BlockSpec((tb, tn), lambda b, i, k: (b, i)),
         ],
         out_specs=pl.BlockSpec((tb, tn), lambda b, i, k: (b, i)),
-        out_shape=jax.ShapeDtypeStruct((fp.shape[0], rp.shape[1]), jnp.uint8),
+        out_shape=jax.ShapeDtypeStruct((fp.shape[0], lp.shape[1]),
+                                       jnp.float32),
         scratch_shapes=[pltpu.VMEM((tb, tn), jnp.float32)],
         interpret=interpret,
-    )(fp, lp, rp, vp)
-    return out[:B, :n]
+    )(fp, lp)[:B, :n]
+    p_act = -jnp.expm1(acc)                     # 1 - exp(acc)
+    new = (rand < p_act) & (visited.astype(jnp.uint8) == 0)
+    return new.astype(jnp.uint8)

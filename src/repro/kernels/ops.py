@@ -1,7 +1,10 @@
 """Jit'd dispatch wrappers: Pallas kernel on TPU, ref.py oracle elsewhere.
 
-``use_pallas=None`` auto-detects the backend.  ``interpret=True`` forces the
-Pallas path through the interpreter (CPU validation — what the tests use).
+The backend decides: on a TPU every wrapper runs the compiled Pallas
+kernel — there is no path to the oracle there.  Off-TPU the jnp oracle
+runs (the CPU test path), and ``interpret=True`` forces the Pallas
+kernel through the interpreter (CPU validation — what the kernel tests
+use).
 
 `ic_frontier_step` is also the execution step of the engine's ``pallas``
 traversal backend (``repro.core.sampler``: ``make_sampler(model,
@@ -41,77 +44,87 @@ def _on_tpu() -> bool:
     return jax.default_backend() == "tpu"
 
 
-def resolve_impl(use_pallas=None, interpret: bool = False) -> str:
-    """The impl a dispatch with these flags routes to, without calling it:
-    ``"interpret"`` (Pallas through the interpreter), ``"pallas"``
-    (compiled kernel), or ``"oracle"`` (the jnp reference)."""
+def resolve_impl(interpret: bool = False) -> str:
+    """The impl a dispatch routes to, without calling it: ``"interpret"``
+    (Pallas through the interpreter), ``"pallas"`` (compiled kernel, the
+    only choice on a TPU), or ``"oracle"`` (the jnp reference, off-TPU)."""
     if interpret:
         return "interpret"
-    if use_pallas or (use_pallas is None and _on_tpu()):
-        return "pallas"
-    return "oracle"
+    return "pallas" if _on_tpu() else "oracle"
 
 
-def _dispatch(kernel: str, use_pallas, interpret) -> bool:
+def _dispatch(kernel: str, interpret) -> bool:
     """Resolve the impl, record ``kernels.dispatch``, return whether the
     Pallas entry point (compiled or interpreted) should run."""
-    impl = resolve_impl(use_pallas, interpret)
+    impl = resolve_impl(interpret)
     obs.counter("kernels.dispatch", kernel=kernel, impl=impl).add(1)
     return impl != "oracle"
 
 
-def coverage_matvec(alive, R, *, use_pallas=None, interpret=False, **kw):
-    if _dispatch("coverage_matvec", use_pallas, interpret):
+def coverage_matvec(alive, R, *, interpret=False, **kw):
+    if _dispatch("coverage_matvec", interpret):
         return _coverage_pallas(alive, R, interpret=interpret, **kw)
     return ref.coverage_matvec_ref(alive, R)
 
 
-def fused_select(alive, R, *, use_pallas=None, interpret=False, **kw):
-    if _dispatch("fused_select", use_pallas, interpret):
+def fused_select(alive, R, *, interpret=False, **kw):
+    if _dispatch("fused_select", interpret):
         return _select_pallas(alive, R, interpret=interpret, **kw)
     return ref.fused_select_ref(alive, R)
 
 
-def ic_frontier_step(frontier, visited, logq, rand, *, use_pallas=None,
-                     interpret=False, **kw):
-    if _dispatch("ic_frontier_step", use_pallas, interpret):
+def ic_frontier_step(frontier, visited, logq, rand, *, interpret=False,
+                     **kw):
+    if _dispatch("ic_frontier_step", interpret):
         return _frontier_pallas(frontier, visited, logq, rand,
                                 interpret=interpret, **kw)
     return ref.ic_frontier_ref(frontier, visited, logq, rand).astype("uint8")
 
 
-def arena_commit(rows, *, kind="bitmap", use_pallas=None, interpret=False,
-                 **kw):
-    if _dispatch("arena_commit", use_pallas, interpret):
+def arena_commit(rows, *, kind="bitmap", interpret=False, **kw):
+    if _dispatch("arena_commit", interpret):
         return _commit_pallas(rows, kind=kind, interpret=interpret, **kw)
     return ref.arena_commit_ref(rows, kind)
 
 
-def packed_count(packed, alive, *, n, use_pallas=None, interpret=False,
-                 **kw):
-    if _dispatch("packed_count", use_pallas, interpret):
+def packed_count(packed, alive, *, n, interpret=False, **kw):
+    if _dispatch("packed_count", interpret):
         return _packed_count_pallas(packed, alive, n=n,
                                     interpret=interpret, **kw)
     return ref.packed_count_ref(packed, alive, n)
 
 
-def token_count(tokens, alive, *, n, use_pallas=None, interpret=False,
-                **kw):
-    if _dispatch("token_count", use_pallas, interpret):
+def token_count(tokens, alive, *, n, interpret=False, **kw):
+    if _dispatch("token_count", interpret):
         return _token_count_pallas(tokens, alive, n=n,
                                    interpret=interpret, **kw)
     return ref.token_count_ref(tokens, alive, n)
 
 
-def fm_interaction(v, *, use_pallas=None, interpret=False, **kw):
-    if _dispatch("fm_interaction", use_pallas, interpret):
+def arena_count(R, alive, *, codec=None, interpret=False):
+    """Per-column count ``(n,) f32`` of the ``alive`` rows of an at-rest
+    arena (or one tile of it) in the layout ``codec`` names — bitmap
+    (``codec`` None or of kind ``"bitmap"``) through `coverage_matvec`,
+    packed and token rows through the decode-and-count kernels — read
+    tile by tile, never widened or decoded whole.  Counts are exact
+    integers in f32."""
+    kind = "bitmap" if codec is None else codec.kind
+    a = alive.astype("float32")
+    if kind == "bitmap":
+        return coverage_matvec(a, R, interpret=interpret)
+    count = packed_count if kind == "packed" else token_count
+    return count(R, a, n=codec.n_cols, interpret=interpret).astype("float32")
+
+
+def fm_interaction(v, *, interpret=False, **kw):
+    if _dispatch("fm_interaction", interpret):
         return _fm_pallas(v, interpret=interpret, **kw)
     return ref.fm_interaction_ref(v)
 
 
-def flash_attention(q, k, v, *, causal=True, window=0, use_pallas=None,
-                    interpret=False, **kw):
-    if _dispatch("flash_attention", use_pallas, interpret):
+def flash_attention(q, k, v, *, causal=True, window=0, interpret=False,
+                    **kw):
+    if _dispatch("flash_attention", interpret):
         return _flash_pallas(q, k, v, causal=causal, window=window,
                              interpret=interpret, **kw)
     return ref.attention_ref(q, k, v, causal=causal, window=window)

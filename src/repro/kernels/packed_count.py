@@ -36,8 +36,10 @@ from jax.experimental.pallas import tpu as pltpu
 from repro.kernels import _pad
 
 _SB = 32        # token superblock: bytes per saturated-run token
-_BASE = 512     # token = block * _BASE + code
+_BASE_LOG2 = 9
+_BASE = 1 << _BASE_LOG2   # token = block * _BASE + code
 _SAT = 256      # code marking a saturated run
+_LANES = 128
 
 
 def _packed_kernel(alive_ref, packed_ref, out_ref, acc_ref):
@@ -49,11 +51,12 @@ def _packed_kernel(alive_ref, packed_ref, out_ref, acc_ref):
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     bytes_ = packed_ref[...].astype(jnp.int32)              # (Tr, Tb)
-    shifts = jax.lax.broadcasted_iota(jnp.int32, (1, 1, 8), 2)
-    bits = ((bytes_[:, :, None] >> shifts) & 1).astype(jnp.float32)
-    bits = bits.reshape(bytes_.shape[0], -1)                # (Tr, Tb*8)
-    acc_ref[...] += jnp.dot(alive_ref[...], bits,
-                            preferred_element_type=jnp.float32)
+    # bit-major accumulator: row i counts bit i of every byte column, so
+    # no lane-interleaving reshape is needed inside the kernel
+    for i in range(8):
+        bits = ((bytes_ >> i) & 1).astype(jnp.float32)
+        acc_ref[i:i + 1, :] += jnp.dot(alive_ref[...], bits,
+                                       preferred_element_type=jnp.float32)
 
     @pl.when(rr == nr - 1)
     def _done():
@@ -63,14 +66,13 @@ def _packed_kernel(alive_ref, packed_ref, out_ref, acc_ref):
 @functools.partial(
     jax.jit, static_argnames=("n", "tile_r", "tile_b", "interpret"))
 def packed_count(packed, alive, *, n: int, tile_r: int = 256,
-                 tile_b: int = 64, interpret: bool = False):
+                 tile_b: int = 512, interpret: bool = False):
     """packed: (theta, ceil(n/8)) uint8, alive: (theta,) f32/bool ->
     counter (n,) int32."""
     theta, nb = packed.shape
     tr, tb = min(tile_r, max(theta, 1)), min(tile_b, nb)
-    # neutral padding: zero bytes decode to zero bits, zero alive rows
-    # contribute nothing
-    pp = _pad.pad_to(_pad.pad_to(packed, 0, tr), 1, tb)
+    # the arena is read in place: bytes past nb only feed output columns
+    # that are sliced off, and rows past theta meet zero-padded alive
     ap = _pad.pad_to(alive.astype(jnp.float32).reshape(1, -1), 1, tr)
     grid = (pl.cdiv(nb, tb), pl.cdiv(theta, tr))
     out = pl.pallas_call(
@@ -80,16 +82,16 @@ def packed_count(packed, alive, *, n: int, tile_r: int = 256,
             pl.BlockSpec((1, tr), lambda j, r: (0, r)),
             pl.BlockSpec((tr, tb), lambda j, r: (r, j)),
         ],
-        out_specs=pl.BlockSpec((1, tb * 8), lambda j, r: (0, j)),
-        out_shape=jax.ShapeDtypeStruct((1, pl.cdiv(nb, tb) * tb * 8),
-                                       jnp.int32),
-        scratch_shapes=[pltpu.VMEM((1, tb * 8), jnp.float32)],
+        out_specs=pl.BlockSpec((8, tb), lambda j, r: (0, j)),
+        out_shape=jax.ShapeDtypeStruct((8, grid[0] * tb), jnp.int32),
+        scratch_shapes=[pltpu.VMEM((8, tb), jnp.float32)],
         interpret=interpret,
-    )(ap, pp)
-    return out[0, :n]
+    )(ap, packed)
+    # counter[8 * byte + bit] = out[bit, byte]
+    return out[:, :nb].T.reshape(-1)[:n]
 
 
-def _token_kernel(alive_ref, tokens_ref, out_ref, acc_ref, *, chunk: int):
+def _token_kernel(alive_ref, tokens_ref, out_ref, acc_ref):
     rr = pl.program_id(1)
     nr = pl.num_programs(1)
 
@@ -97,28 +99,43 @@ def _token_kernel(alive_ref, tokens_ref, out_ref, acc_ref, *, chunk: int):
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    toks = tokens_ref[...]                                  # (Tr, S) int32
-    tr, s_pad = toks.shape
+    tr, s_pad = tokens_ref.shape
     tn = out_ref.shape[-1]
     cols = (pl.program_id(0) * tn
             + jax.lax.broadcasted_iota(jnp.int32, (1, tn), 1))
     cblk = cols >> 3                                        # (1, Tn)
     cbit = cols & 7
-    csb = (cblk // _SB) * _SB
-    bits = jnp.zeros((tr, tn), jnp.float32)
-    for s0 in range(0, s_pad, chunk):
-        t = toks[:, s0:s0 + chunk]                          # (Tr, CH)
-        blk = t // _BASE
-        code = t - blk * _BASE
-        lit = ((code < _SAT)[:, :, None]
-               & (blk[:, :, None] == cblk[None, :, :])
-               & (((code[:, :, None] >> cbit[None, :, :]) & 1) > 0))
-        sat = ((code == _SAT)[:, :, None]
-               & (blk[:, :, None] == csb[None, :, :]))
-        bits = jnp.maximum(
-            bits, (lit | sat).any(axis=1).astype(jnp.float32))
-    acc_ref[...] += jnp.dot(alive_ref[...], bits,
-                            preferred_element_type=jnp.float32)
+    csb = cblk & ~(_SB - 1)
+
+    def one_token(t, hit):
+        # one (Tr, 1) token column against the tile's (1, Tn) column ids:
+        # every operand stays 2D, broadcast along lanes or sublanes
+        blk = t >> _BASE_LOG2
+        code = t & (_BASE - 1)
+        lit = ((code < _SAT) & (blk == cblk)
+               & (((code >> cbit) & 1) > 0))
+        sat = (code == _SAT) & (blk == csb)
+        return hit | (lit | sat).astype(jnp.int32)
+
+    def chunk(toks, hit):
+        for j in range(toks.shape[1]):
+            hit = one_token(toks[:, j:j + 1], hit)
+        return hit
+
+    hit = jnp.zeros((tr, tn), jnp.int32)
+    if s_pad <= _LANES:
+        hit = chunk(tokens_ref[...], hit)
+    else:
+        # wider token rows are read in 128-lane chunks (Mosaic needs
+        # lane-aligned dynamic offsets; s_pad is a power of two)
+        hit = jax.lax.fori_loop(
+            0, s_pad // _LANES,
+            lambda c, h: chunk(tokens_ref[:, pl.ds(
+                pl.multiple_of(c * _LANES, _LANES), _LANES)], h), hit)
+    # alive arrives as a (Tr, 1) column: a VPU masked row-sum, exact in
+    # f32 like the oracle's matmul
+    acc_ref[...] += jnp.sum(alive_ref[...] * hit.astype(jnp.float32),
+                            axis=0, keepdims=True)
 
     @pl.when(rr == nr - 1)
     def _done():
@@ -126,11 +143,9 @@ def _token_kernel(alive_ref, tokens_ref, out_ref, acc_ref, *, chunk: int):
 
 
 @functools.partial(
-    jax.jit,
-    static_argnames=("n", "tile_r", "tile_n", "chunk", "interpret"))
+    jax.jit, static_argnames=("n", "tile_r", "tile_n", "interpret"))
 def token_count(tokens, alive, *, n: int, tile_r: int = 8,
-                tile_n: int = 256, chunk: int = 8,
-                interpret: bool = False):
+                tile_n: int = 256, interpret: bool = False):
     """tokens: (theta, s_pad) int32 (see codec format), alive: (theta,)
     f32/bool -> counter (n,) int32.  Sentinel tokens (code 0 at the
     past-the-end block) decode to nothing; pad columns past ``n`` stay
@@ -139,15 +154,14 @@ def token_count(tokens, alive, *, n: int, tile_r: int = 8,
     tr = min(tile_r, max(theta, 1))
     tn = tile_n
     tp = _pad.pad_to(tokens, 0, tr)  # zero-pad rows: block 0 code 0 -> no bits
-    ap = _pad.pad_to(alive.astype(jnp.float32).reshape(1, -1), 1, tr)
+    ap = _pad.pad_to(alive.astype(jnp.float32).reshape(-1, 1), 0, tr)
     ncols = -(-n // tn) * tn
     grid = (ncols // tn, pl.cdiv(theta, tr))
-    kernel = functools.partial(_token_kernel, chunk=min(chunk, s_pad))
     out = pl.pallas_call(
-        kernel,
+        _token_kernel,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, tr), lambda j, r: (0, r)),
+            pl.BlockSpec((tr, 1), lambda j, r: (r, 0)),
             pl.BlockSpec((tr, s_pad), lambda j, r: (r, 0)),
         ],
         out_specs=pl.BlockSpec((1, tn), lambda j, r: (0, j)),

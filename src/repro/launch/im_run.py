@@ -25,6 +25,7 @@ import json
 import time
 
 from repro import obs
+from repro.launch.compile_cache import init_compile_cache
 from repro.configs.imm_snap import (
     IMM_EXPERIMENTS, make_im_mesh, mesh_engine_kwargs,
 )
@@ -38,6 +39,10 @@ def run(graph: str, *, scale: float = None, model: str = "IC", k: int = 50,
         mesh=None, backend: str = None, sampler: str = None,
         store: str = "auto", metrics_out: str = None, trace_out: str = None,
         log=print):
+    """Build the graph and engine, run IMM, answer ``select_ks`` from the
+    same store, and log one JSON line.  Returns ``(out, engine)``: the
+    logged record and the engine, whose store stays resident for more
+    ``select``/``influences`` queries."""
     if metrics_out or trace_out:
         obs.enable()
     exp = IMM_EXPERIMENTS[graph]
@@ -94,7 +99,7 @@ def run(graph: str, *, scale: float = None, model: str = "IC", k: int = 50,
     if trace_out:
         out["trace_out"] = obs.write_trace(trace_out)
     log(json.dumps(out))
-    return out
+    return out, engine
 
 
 def main(argv=None):
@@ -142,6 +147,7 @@ def main(argv=None):
                     help="enable repro.obs and write the Chrome "
                          "trace-event JSON (Perfetto-loadable) here")
     args = ap.parse_args(argv)
+    init_compile_cache()
     run(args.graph, scale=args.scale, model=args.model, k=args.k,
         eps=args.eps, baseline=args.baseline, max_theta=args.max_theta,
         select_ks=args.select_k, snapshot_dir=args.snapshot_dir,

@@ -30,54 +30,30 @@ from repro.launch.mesh import TPU_V5E
 
 # ------------------------------------------------- device peak table ----
 #
-# Peaks keyed by ``device_kind`` (what ``jax.devices()[0].platform`` /
-# benchmarks._emit.device_kind() report).  The TPU row is the v5e the
-# production mesh targets (launch/mesh.py); the GPU row is an A100-class
-# part (dense bf16 tensor-core peak, HBM2e, NVLink per direction); the
-# CPU row is a deliberately round-number server-class socket estimate
-# (AVX-512 F32 throughput, dual-channel-ish DRAM) so CPU BENCH rows get
-# an order-of-magnitude achieved fraction rather than a meaningless one.
-# The "unknown" fallback is tiny on purpose: an unrecognized platform
-# reports achieved_frac ~ 1.0-clamped garbage loudly instead of quietly
-# flattering numbers.
+# Peaks keyed by ``jax.devices()[0].device_kind``.  The one row is the
+# TPU v5e this repository targets, with its published peaks (Google Cloud
+# documentation, "TPU v5e"; `repro.launch.mesh.TPU_V5E`).  A device that
+# is not in the table is an error: no device metric is ever computed
+# against a guessed peak, and a CPU run computes none.
 
 HW_PEAKS = {
-    "tpu": TPU_V5E,
-    "gpu": {
-        "name": "A100-40G class",
-        "peak_flops_bf16": 312e12,
-        "hbm_bytes_per_s": 1.555e12,
-        "ici_bytes_per_s": 300e9,
-        "hbm_bytes": 40 * 2**30,
-    },
-    "cpu": {
-        "name": "server CPU (estimate)",
-        "peak_flops_bf16": 1e12,
-        "hbm_bytes_per_s": 5e10,
-        "ici_bytes_per_s": 1e10,
-        "hbm_bytes": 64 * 2**30,
-    },
-    "unknown": {
-        "name": "unknown device",
-        "peak_flops_bf16": 1e9,
-        "hbm_bytes_per_s": 1e9,
-        "ici_bytes_per_s": 1e9,
-        "hbm_bytes": 1 * 2**30,
-    },
+    "TPU v5 lite": TPU_V5E,
 }
 
 
 def peaks_for(device_kind: str | None = None) -> dict:
-    """The `HW_PEAKS` row for ``device_kind`` (auto-detected from the
-    default jax backend when None; anything unrecognized gets the
-    explicit "unknown" fallback, never a KeyError)."""
+    """The `HW_PEAKS` row for ``device_kind`` (default: the
+    ``device_kind`` of ``jax.devices()[0]``); raises ``KeyError`` for a
+    device the table does not know."""
     if device_kind is None:
-        try:
-            import jax
-            device_kind = jax.devices()[0].platform
-        except Exception:
-            device_kind = "unknown"
-    return HW_PEAKS.get(str(device_kind), HW_PEAKS["unknown"])
+        import jax
+        device_kind = jax.devices()[0].device_kind
+    try:
+        return HW_PEAKS[str(device_kind)]
+    except KeyError:
+        raise KeyError(
+            f"no published peaks for device_kind {device_kind!r}; known: "
+            f"{sorted(HW_PEAKS)}") from None
 
 
 # --------------------------------------------- per-kernel cost models ----

@@ -51,6 +51,7 @@ import jax
 import jax.numpy as jnp
 
 from repro import obs
+from repro.launch.compile_cache import init_compile_cache
 from repro.configs import get_arch
 from repro.models.transformer import (
     LMConfig, init_lm, prefill, decode_step, init_kv_cache,
@@ -528,6 +529,7 @@ def main(argv=None):
                     help="enable repro.obs and write the Chrome "
                          "trace-event JSON (Perfetto-loadable) here")
     args = ap.parse_args(argv)
+    init_compile_cache()
     if args.metrics_out or args.trace_out:
         obs.enable()
     if args.workload == "tier":

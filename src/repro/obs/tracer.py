@@ -101,11 +101,8 @@ class Tracer:
         self.dropped = 0
         self._annotate = None
         if jax_annotations:
-            try:
-                from jax.profiler import TraceAnnotation
-                self._annotate = TraceAnnotation
-            except Exception:            # profiler unavailable: host-only
-                self._annotate = None
+            from jax.profiler import TraceAnnotation
+            self._annotate = TraceAnnotation
 
     # ------------------------------------------------------------ record
 

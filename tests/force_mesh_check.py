@@ -42,6 +42,7 @@ from repro.configs.imm_snap import make_im_mesh, mesh_engine_kwargs
 from repro.core.engine import InfluenceEngine, IMMConfig
 from repro.core.store import BitmapStore, ShardedStore
 from repro.graphs import balance_report, rmat_graph
+from repro.launch.mesh import make_mesh
 
 
 def main(argv=None):
@@ -186,7 +187,7 @@ def main(argv=None):
             g, cfg, **mesh_engine_kwargs(make_im_mesh(n_dev)))
         assert on1d.restore(d)
         np.testing.assert_array_equal(on1d.select(5).seeds, r_dense.seeds)
-        on1 = InfluenceEngine(g, cfg, mesh=jax.make_mesh((1,), ("data",)))
+        on1 = InfluenceEngine(g, cfg, mesh=make_mesh((1,), ("data",)))
         assert on1.restore(d)
         np.testing.assert_array_equal(on1.select(5).seeds, r_dense.seeds)
         flat = InfluenceEngine(g, cfg)

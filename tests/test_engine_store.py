@@ -18,6 +18,7 @@ from repro.core.store import (
     store_from_state,
 )
 from repro.graphs import path_graph, rmat_graph
+from repro.launch.mesh import make_mesh
 
 
 def _random_batches(rng, n, batches, batch):
@@ -291,7 +292,7 @@ def test_selection_registry_covers_matrix_and_rejects():
 
 def test_sharded_strategy_through_engine_matches_local():
     """Sharded selection via the strategy interface == local selection."""
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     g = rmat_graph(128, 1024, seed=4)
     cfg = IMMConfig(k=5, batch=64, max_theta=256)
     local = InfluenceEngine(g, cfg)

@@ -16,6 +16,7 @@ from repro.models.gnn.irreps import (
     rotation_to_align_z, wigner_d_stack, sph_harm_from_wigner,
 )
 from repro.graphs.sampler import neighbor_sampler
+from repro.launch.mesh import make_mesh
 
 
 def _graph(n=14, e=50, seed=0, d_feat=8):
@@ -218,7 +219,7 @@ def test_graphcast_dst_partitioned_equals_plain():
     nf, pos, es, ed = _graph(d_feat=5)
     ef = jax.random.normal(jax.random.PRNGKey(9), (50, 4))
     o1 = m_gc.forward_edges(p, cfg, nf, ef, es, ed, 14)
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     cfg2 = dataclasses.replace(cfg, node_axes=("data",), remat_group=2,
                                remat=True)
     with mesh:

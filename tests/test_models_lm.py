@@ -10,6 +10,7 @@ from repro.models.transformer import (
 )
 from repro.models.attention import blockwise_attention, apply_rope
 from repro.kernels import ref as kref
+from repro.launch.mesh import make_mesh
 
 
 CFG = LMConfig(n_layers=2, d_model=32, n_heads=4, n_kv_heads=2, d_ff=64,
@@ -178,7 +179,7 @@ def test_moe_shard_map_matches_dense_path():
     p = init_lm(jax.random.PRNGKey(0), cfg)
     toks = _toks(vocab=128)
     l_ref = float(lm_loss(p, cfg, toks, toks))
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     moe_sharded.MESH = mesh
     for part in ("tpe", "ep"):
         cfg2 = dataclasses.replace(cfg, moe_impl="shard_map",

@@ -21,6 +21,7 @@ from repro.runtime.compression import (
     compress_with_feedback,
 )
 from repro.runtime.elastic import reshard_tree, replicated_plan
+from repro.launch.mesh import make_mesh
 
 settings.register_profile("ci3", deadline=None, max_examples=20)
 settings.load_profile("ci3")
@@ -184,7 +185,7 @@ def test_error_feedback_reduces_bias():
 # ---------------------------------------------------------------- elastic ----
 
 def test_reshard_tree_roundtrip():
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     tree = {"w": np.arange(8.0), "b": [np.ones((2, 2))]}
     out = reshard_tree(tree, replicated_plan(mesh))
     np.testing.assert_array_equal(np.asarray(out["w"]), tree["w"])
@@ -197,7 +198,7 @@ def test_checkpoint_then_reshard_elasticity():
     with tempfile.TemporaryDirectory() as d:
         save_checkpoint(d, 1, {"w": jnp.arange(16.0).reshape(4, 4)})
         _, host_tree = load_checkpoint(d)
-        mesh2 = jax.make_mesh((1, 1), ("data", "model"))
+        mesh2 = make_mesh((1, 1), ("data", "model"))
         out = reshard_tree(host_tree, replicated_plan(mesh2))
         np.testing.assert_array_equal(
             np.asarray(out["w"]), np.arange(16.0).reshape(4, 4))

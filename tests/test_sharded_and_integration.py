@@ -13,6 +13,7 @@ import pytest
 
 from repro.core.selection import select_dense, select_dense_sharded
 from repro.launch.hlo_analysis import analyze_module, parse_module
+from repro.launch.mesh import make_mesh
 
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -20,7 +21,7 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 def test_select_dense_sharded_equals_local():
     """The psum-combined sharded selection (paper C1) == single-device."""
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     rng = np.random.default_rng(0)
     R = jnp.asarray((rng.random((64, 32)) < 0.3).astype(np.uint8))
     valid = jnp.ones((64,), bool)
@@ -109,8 +110,9 @@ def test_serve_generates():
 
 def test_im_run_end_to_end():
     from repro.launch.im_run import run
-    out = run("com-Amazon", scale=0.002, model="IC", k=5,
-              max_theta=512, log=lambda *a: None)
+    out, engine = run("com-Amazon", scale=0.002, model="IC", k=5,
+                      max_theta=512, log=lambda *a: None)
+    assert engine.theta == out["theta"]
     assert out["influence"] > 0
     assert len(out["seeds"]) >= 5
 

@@ -30,12 +30,13 @@ from repro.core.store import (
     BitmapStore, ShardedStore, make_store, store_from_state,
 )
 from repro.graphs import balanced_vertex_partition, rmat_graph
+from repro.launch.mesh import make_mesh
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 def theta_mesh(shards: int = None):
-    return jax.make_mesh((shards or jax.device_count(),), ("data",))
+    return make_mesh((shards or jax.device_count(),), ("data",))
 
 
 def im_mesh_2d():

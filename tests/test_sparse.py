@@ -117,10 +117,11 @@ def test_embedding_bag_modes():
 def test_sharded_embedding_lookup_single_device():
     """shard_map row-sharded lookup == plain take on a 1-device mesh."""
     from repro.compat import shard_map
+    from repro.launch.mesh import make_mesh
     from repro.sparse import sharded_embedding_lookup
     from jax.sharding import PartitionSpec as P
 
-    mesh = jax.make_mesh((1,), ("model",))
+    mesh = make_mesh((1,), ("model",))
     table = jax.random.normal(jax.random.PRNGKey(0), (16, 4))
     ids = jnp.array([[0, 3], [15, 7]], jnp.int32)
     fn = shard_map(

@@ -27,10 +27,11 @@ from repro.stream import (
     GraphDelta, StreamEngine, canonicalize, invalidate, random_delta,
     rows_touching,
 )
+from repro.launch.mesh import make_mesh
 
 
 def theta_mesh():
-    return jax.make_mesh((jax.device_count(),), ("data",))
+    return make_mesh((jax.device_count(),), ("data",))
 
 
 def small_graph(seed=2):

@@ -1,0 +1,118 @@
+"""Compile the IM kernels and the dense selection program for a TPU v5e
+that is described, not attached, at com-Amazon's full width.
+
+Interpret mode runs a kernel's semantics on the CPU; it cannot see what
+the chip's compiler refuses (block shapes off the (8, 128) tiling, casts
+Mosaic does not lower, programs that do not fit HBM).  These compiles
+can: each kernel must lower to a ``tpu_custom_call``, and ``select_dense``
+over a 16,384 x 334,863 uint8 arena must fit the chip's 16 GiB.
+
+The topology is described inside a module-scoped fixture, so a worker
+that cannot describe it skips these tests and every worker collects the
+same ones.  The persistent compilation cache is off around the compiles:
+an entry compiled for a described chip cannot be read back without one.
+"""
+import os
+
+import pytest
+
+N = 334_863          # com-Amazon |V|
+THETA = 16_384       # the default --max-theta
+B = 256              # IMMConfig.batch
+HBM_BYTES = 16 * 2**30
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    import jax
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    from jax.sharding import SingleDeviceSharding
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:       # no TPU compiler here: nothing to check
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+def _spec(one_chip, shape, dtype):
+    import jax
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+
+def _kernel_case(name, one_chip):
+    import jax.numpy as jnp
+    from repro.kernels.commit import arena_commit
+    from repro.kernels.coverage_matvec import coverage_matvec
+    from repro.kernels.fused_select import fused_select
+    from repro.kernels.ic_frontier import ic_frontier_step
+    from repro.kernels.packed_count import packed_count, token_count
+
+    S = lambda shape, dt: _spec(one_chip, shape, dt)    # noqa: E731
+    words = -(-N // 8)
+    return {
+        "arena_commit_bitmap": (lambda r: arena_commit(r, kind="bitmap"),
+                                [S((B, N), jnp.uint8)]),
+        "arena_commit_packed": (lambda r: arena_commit(r, kind="packed"),
+                                [S((B, N), jnp.uint8)]),
+        "coverage_matvec": (coverage_matvec,
+                            [S((THETA,), jnp.float32),
+                             S((THETA, N), jnp.uint8)]),
+        "fused_select": (fused_select, [S((THETA,), jnp.float32),
+                                        S((THETA, N), jnp.uint8)]),
+        "packed_count": (lambda p, a: packed_count(p, a, n=N),
+                         [S((THETA, words), jnp.uint8),
+                          S((THETA,), jnp.float32)]),
+        # narrow token rows unroll in one block, wide ones loop over
+        # 128-lane chunks: both shapes of the kernel
+        "token_count_s64": (lambda t, a: token_count(t, a, n=N),
+                            [S((THETA, 64), jnp.int32),
+                             S((THETA,), jnp.float32)]),
+        "token_count_s512": (lambda t, a: token_count(t, a, n=N),
+                             [S((THETA, 512), jnp.int32),
+                              S((THETA,), jnp.float32)]),
+        "ic_frontier_n4096": (ic_frontier_step,
+                              [S((B, 4096), jnp.uint8),
+                               S((B, 4096), jnp.uint8),
+                               S((4096, 4096), jnp.float32),
+                               S((B, 4096), jnp.float32)]),
+    }[name]
+
+
+@pytest.mark.parametrize("name", [
+    "arena_commit_bitmap", "arena_commit_packed", "coverage_matvec",
+    "fused_select", "packed_count", "token_count_s64", "token_count_s512",
+    "ic_frontier_n4096"])
+def test_kernel_compiles_for_v5e(one_chip, name):
+    import jax
+    fn, args = _kernel_case(name, one_chip)
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("method", ["rebuild", "decrement"])
+def test_select_dense_fits_v5e(one_chip, method, monkeypatch):
+    import jax
+    import jax.numpy as jnp
+    from repro.core.selection import select_dense
+    from repro.kernels import ops
+
+    # the described chip is not the default backend: steer the kernel
+    # dispatch to the branch it takes on a TPU
+    monkeypatch.setattr(ops, "_on_tpu", lambda: True)
+    compiled = jax.jit(lambda R, v: select_dense(R, v, 50, method)).lower(
+        _spec(one_chip, (THETA, N), jnp.uint8),
+        _spec(one_chip, (THETA,), jnp.bool_)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    mem = compiled.memory_analysis()
+    total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+             + mem.temp_size_in_bytes)
+    assert total < HBM_BYTES, f"select_dense needs {total / 2**30:.2f} GiB"
