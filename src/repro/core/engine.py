@@ -276,8 +276,10 @@ class InfluenceEngine:
         """
         cap = getattr(self.store, "row_cap", None)
         target = theta if cap is None else min(theta, cap)
-        with obs.span("extend", tier="engine", target=target):
+        batches = 0
+        with obs.span("extend", tier="engine", target=target) as sp:
             while self.store.count < target:
+                batches += 1
                 self.key, sub = jax.random.split(self.key)
                 if self._emit_l:
                     with obs.span("sample", tier="engine",
@@ -293,6 +295,8 @@ class InfluenceEngine:
                         visited, counter, _ = self._sample(sub)
                     self.store.add_batch(visited, counter)
                 obs.counter("engine.batches_sampled").add(1)
+            if sp is not None:
+                sp.set(batches=batches)
         obs.gauge("engine.theta").set(self.store.count)
         return self.store.count
 

@@ -81,20 +81,56 @@ def make_fused_extender(store, sample, cfg, *, sampler_name: str):
     return None
 
 
+def _sample_counted(sample, key):
+    """``(visited, steps)`` from the bound sampler: ``steps`` is its
+    while loop's trip count, or None for a sampler that does not count
+    (`repro.core.sampler.TraversalBackend`)."""
+    if getattr(sample, "coins_per_step", None) is None:
+        return sample(key)[0], None
+    visited, _, _, steps = sample(key, with_steps=True)
+    return visited, steps
+
+
+def _work(sample, steps, colsum) -> dict:
+    """The batch's device counts the chain returns beside its commit
+    (two int32 scalars, dropped by the caller when obs is off): the
+    loop's ``steps`` and, for coin models, ``consulted``, the (row,
+    edge) pairs the traversal tried: ``colsum . in_degree``, since each
+    member of a row fronts once and tries each of its in-edges once."""
+    if steps is None:
+        return {}
+    work = {"steps": steps}
+    in_degree = getattr(sample, "in_degree", None)
+    if in_degree is not None:
+        work["consulted"] = (colsum * in_degree).sum(dtype=jnp.int32)
+    return work
+
+
+def _sample_args(sample, batch: int, work: dict) -> dict:
+    """The fused ``sample`` span's arguments: ``sets``, and the device
+    counts of `_work` with ``coins = steps x coins_per_step``, formed as
+    a Python int at export (a batch's coins can pass 2**31)."""
+    args = {"sets": batch, **work}
+    if "steps" in work:
+        args["coins"] = obs.Deferred(work["steps"], sample.coins_per_step)
+    return args
+
+
 def _make_chain_fn(sample, kind: str, interpret: bool):
     """The fixed-shape half of a single-device fused batch: sample ->
     arena_commit (encode + column count in one kernel pass).  Shapes
     depend only on (batch, n), never on arena capacity, so this — the
     program that contains the whole sampler trace — compiles exactly
-    once per at-rest kind."""
+    once per at-rest kind.  It also returns the batch's `_work`."""
 
     @jax.jit
     def chain(key):
-        visited, _, _ = sample(key)
+        visited, steps = _sample_counted(sample, key)
         visited = visited.astype(jnp.uint8)
         stored, colsum = kops.arena_commit(visited, kind=kind,
                                            interpret=interpret)
-        return stored, visited.sum(axis=1, dtype=jnp.int32), colsum
+        return (stored, visited.sum(axis=1, dtype=jnp.int32), colsum,
+                _work(sample, steps, colsum))
 
     return chain
 
@@ -144,13 +180,18 @@ class _ArenaFused:
         # siblings under the engine's extend — the same topology the
         # unfused sample + add_batch path reports
         with obs.span("sample", tier="engine", sampler=self.sampler_name,
-                      fused=True):
-            stored, batch_sizes, colsum = fn(key)
+                      fused=True) as sp:
+            stored, batch_sizes, colsum, work = fn(key)
+            if sp is not None:
+                sp.set(**_sample_args(self._sample, B, work))
         with obs.span("store.write", tier="store", kind=kind,
-                      fused=True):
+                      fused=True) as sp:
             s.R, s.sizes, s.counter = _commit_write(
                 s.R, s.sizes, s.counter, stored, batch_sizes, colsum,
                 jnp.int32(s.count))
+            if sp is not None:
+                # rows and sizes written, the counter read and written
+                sp.set(sets=B, bytes=B * s._row_bytes() + 4 * B + 8 * s.n)
         s._note_write(B)
         return True
 
@@ -183,13 +224,15 @@ def _make_sharded_chain(sample, store, batch: int):
 
     @jax.jit
     def chain(key, incs):
-        visited, _, _ = sample(key)
-        visited = s._layout_cols(visited.astype(jnp.uint8))
+        visited, steps = _sample_counted(sample, key)
+        visited = visited.astype(jnp.uint8)
+        work = _work(sample, steps, visited.sum(axis=0, dtype=jnp.int32))
+        visited = s._layout_cols(visited)
         if pad:
             visited = jnp.concatenate(
                 [visited, jnp.zeros((pad, s.n_pad), jnp.uint8)])
         visited = jax.lax.with_sharding_constraint(visited, s._sh_rows)
-        return enc(visited, incs)
+        return enc(visited, incs) + (work,)
 
     return chain
 
@@ -249,13 +292,22 @@ class _ShardedFused:
         commit = _sharded_commit_fn(s.mesh, s.theta_axes, s.vertex_axis)
         incs = jax.device_put(jnp.asarray(self._incs_np), s._sh_vec)
         with obs.span("sample", tier="engine", sampler=self.sampler_name,
-                      fused=True):
-            stored, row_sizes, counter_d = fn(key, incs)
+                      fused=True) as sp:
+            stored, row_sizes, counter_d, work = fn(key, incs)
+            if sp is not None:
+                sp.set(**_sample_args(self._sample, self.batch, work))
         with obs.span("store.write", tier="store", kind=s.codec.kind,
-                      fused=True):
+                      fused=True) as sp:
             s.R, s.sizes, s._counter, s._counts = commit(
                 s.R, s.sizes, s._counter, s._counts, stored,
                 row_sizes, counter_d, incs)
             s._counts_host += self._incs_np
+            if sp is not None:
+                # every shard's b rows and sizes written (padding rows
+                # too), the counter partials read and written
+                rows = self._b * s.D
+                sp.set(sets=self.batch,
+                       bytes=rows * s._row_bytes() + 4 * rows
+                       + 8 * int(np.prod(s._counter.shape)))
         s._note_write(self.batch)
         return True

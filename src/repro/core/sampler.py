@@ -321,11 +321,12 @@ def _setup(key, batch, n_nodes, positions, placement, stable):
 # stable path reproduces the historical ``-stable`` twins bitwise.
 
 @partial(jax.jit, static_argnames=("batch", "max_steps", "stable", "kernel",
-                                   "interpret", "placement", "overlap"))
+                                   "interpret", "placement", "overlap",
+                                   "with_steps"))
 def _dense_loop(key, logq, positions=None, *, batch: int, max_steps: int = 0,
                 stable: bool = False, kernel: bool = False,
                 interpret: bool = False, placement=None,
-                overlap: bool = False):
+                overlap: bool = False, with_steps: bool = False):
     """Dense log-semiring frontier expansion (the ``dense`` and
     ``pallas`` backends; ``kernel=True`` routes the step through
     ``kernels.ops.ic_frontier_step`` — same math, fused on the MXU).
@@ -343,7 +344,8 @@ def _dense_loop(key, logq, positions=None, *, batch: int, max_steps: int = 0,
 
     Returns ``(visited (K, n) uint8, counter (n,) int32, roots (K,))``
     where ``K = len(positions)`` (the full batch when ``positions`` is
-    None; positional mode requires ``positions is None``).
+    None; positional mode requires ``positions is None``), and with
+    ``with_steps`` the while loop's trip count (int32) fourth.
     """
     n = logq.shape[0]
     max_steps = max_steps or n
@@ -390,18 +392,20 @@ def _dense_loop(key, logq, positions=None, *, batch: int, max_steps: int = 0,
         # nothing downstream in this body depends on the gathered copy
         return step + 1, gather(new), jnp.logical_or(visited, new), k
 
-    _, _, visited, _ = jax.lax.while_loop(
+    steps, _, visited, _ = jax.lax.while_loop(
         cond, body, (jnp.int32(0), gather(visited0), visited0, kstep)
     )
     counter = visited.sum(axis=0, dtype=jnp.int32)      # fused count (C3)
-    return visited.astype(jnp.uint8), counter, roots
+    out = (visited.astype(jnp.uint8), counter, roots)
+    return out + (steps,) if with_steps else out
 
 
 @partial(jax.jit, static_argnames=("n_nodes", "batch", "max_steps", "stable",
-                                   "placement", "emit_l"))
+                                   "placement", "emit_l", "with_steps"))
 def _sparse_loop(key, edge_src, edge_dst, edge_prob, positions=None, *,
                  n_nodes: int, batch: int, max_steps: int = 0,
-                 stable: bool = False, placement=None, emit_l: int = 0):
+                 stable: bool = False, placement=None, emit_l: int = 0,
+                 with_steps: bool = False):
     """CSC edge-list frontier expansion (the ``sparse`` backend).
 
     An edge ``u -> v`` is consulted when ``v`` is in the reverse
@@ -421,6 +425,10 @@ def _sparse_loop(key, edge_src, edge_dst, edge_prob, positions=None, *,
     bit.  Rows with more than ``emit_l`` members are truncated — callers
     grow ``emit_l`` and re-emit when a row comes back full (same key,
     same coins, wider lists).
+
+    ``with_steps`` appends the while loop's trip count (int32): one
+    step per BFS level reached, plus the step that finds the frontier
+    empty, so one more than the deepest level of the batch's rows.
     """
     m = edge_src.shape[0]
     max_steps = max_steps or n_nodes
@@ -454,21 +462,23 @@ def _sparse_loop(key, edge_src, edge_dst, edge_prob, positions=None, *,
         new = jnp.logical_and(new, ~visited)
         return step + 1, new, jnp.logical_or(visited, new), k
 
-    _, _, visited, _ = jax.lax.while_loop(
+    steps, _, visited, _ = jax.lax.while_loop(
         cond, body, (jnp.int32(0), visited0, visited0, kstep)
     )
     counter = visited.sum(axis=0, dtype=jnp.int32)
     if emit_l:
-        return bitmap_to_indices(visited.astype(jnp.uint8),
-                                 emit_l), counter, roots
-    return visited.astype(jnp.uint8), counter, roots
+        out = (bitmap_to_indices(visited.astype(jnp.uint8), emit_l),
+               counter, roots)
+    else:
+        out = (visited.astype(jnp.uint8), counter, roots)
+    return out + (steps,) if with_steps else out
 
 
 @partial(jax.jit, static_argnames=("batch", "max_steps", "max_indeg_log2",
-                                   "stable", "placement"))
+                                   "stable", "placement", "with_steps"))
 def _walk_loop(key, dst_offsets, in_src, in_cum, in_total, positions=None, *,
                batch: int, max_steps: int = 0, max_indeg_log2: int = 32,
-               stable: bool = False, placement=None):
+               stable: bool = False, placement=None, with_steps: bool = False):
     """Pick-at-most-one random walk (the ``walk`` backend, `WalkModel`).
 
     Each step the walk at ``cur`` draws one uniform ``r``: ``r >=
@@ -483,6 +493,10 @@ def _walk_loop(key, dst_offsets, in_src, in_cum, in_total, positions=None, *,
     is data-dependent and uniformly random over vertices, so there is no
     block locality for a column partition to exploit — tables are
     O(m + n) scalars, not O(n^2).
+
+    ``with_steps`` appends the while loop's trip count (int32): the
+    longest walk's moves plus the step that stops it, which is the
+    batch's largest row size.
     """
     n = dst_offsets.shape[0] - 1
     max_steps = max_steps or n
@@ -531,12 +545,13 @@ def _walk_loop(key, dst_offsets, in_src, in_cum, in_total, positions=None, *,
         cur = jnp.where(go, nxt, cur)
         return step + 1, cur, go, visited, k
 
-    _, _, _, visited, _ = jax.lax.while_loop(
+    steps, _, _, visited, _ = jax.lax.while_loop(
         cond, body, (jnp.int32(0), roots, jnp.ones(roots.shape, jnp.bool_),
                      visited0, kstep)
     )
     counter = visited.sum(axis=0, dtype=jnp.int32)
-    return visited.astype(jnp.uint8), counter, roots
+    out = (visited.astype(jnp.uint8), counter, roots)
+    return out + (steps,) if with_steps else out
 
 
 # ------------------------------------------------- historical entry points ----
@@ -615,6 +630,17 @@ def _pad_edges_pow2(edge_src, edge_dst, edge_prob):
             jnp.concatenate([edge_prob, jnp.zeros((pad,), edge_prob.dtype)]))
 
 
+def _states_work(fn, coins_per_step: int, graph, cfg):
+    """Tag a bound sampler with the work it does (`TraversalBackend`).
+    ``graph`` is None for walk models; ``in_degree`` is left None where
+    a batch's consulted pairs, at most ``batch * m``, could pass int32."""
+    fn.coins_per_step = int(coins_per_step)
+    fn.in_degree = (graph.in_degree()
+                    if graph is not None and cfg.batch * graph.m < 2**31
+                    else None)
+    return fn
+
+
 @dataclasses.dataclass(frozen=True)
 class TraversalBackend:
     """One way to execute an RRR traversal.
@@ -625,6 +651,13 @@ class TraversalBackend:
     tables) and returns the bound sampler: a callable of a PRNG key —
     plus a keyword-only ``positions`` row subset when ``stable`` —
     returning ``(visited (B, n) uint8, counter (n,) int32, roots (B,))``.
+
+    The built-in backends' bound samplers also take ``with_steps=True``
+    (the loop's trip count is then returned fourth) and state their
+    work: ``coins_per_step``, the uniforms one loop step draws for a
+    full batch, and for coin models ``in_degree``, the ``(n,) int32``
+    in-degrees, so a batch consults ``colsum . in_degree`` (row, edge)
+    pairs (each member fronts once and tries each in-edge once).
     """
     name: str
     family: str
@@ -640,13 +673,15 @@ def _bind_dense(model, graph: Graph, cfg, *, stable, placement,
     # the flag on 1D/absent placements where there is no collective)
     overlap = bool(getattr(cfg, "overlap", True))
     if stable:
-        return lambda key, positions=None: _dense_loop(
+        fn = lambda key, positions=None, with_steps=False: _dense_loop(
             key, logq, positions, batch=cfg.batch, stable=True,
             kernel=kernel, interpret=interpret, placement=placement,
-            overlap=overlap)
-    return lambda key: _dense_loop(
-        key, logq, batch=cfg.batch, kernel=kernel, interpret=interpret,
-        placement=placement, overlap=overlap)
+            overlap=overlap, with_steps=with_steps)
+    else:
+        fn = lambda key, with_steps=False: _dense_loop(
+            key, logq, batch=cfg.batch, kernel=kernel, interpret=interpret,
+            placement=placement, overlap=overlap, with_steps=with_steps)
+    return _states_work(fn, cfg.batch * graph.n, graph, cfg)
 
 
 def _bind_pallas(model, graph: Graph, cfg, *, stable, placement):
@@ -663,29 +698,34 @@ def _bind_sparse(model, graph: Graph, cfg, *, stable, placement):
         # positional sampler keeps the exact edge count (seed parity
         # with the historical IC-sparse stream)
         src, dst, prob = _pad_edges_pow2(src, dst, prob)
-        fn = lambda key, positions=None, emit_l=0: _sparse_loop(
-            key, src, dst, prob, positions, n_nodes=graph.n,
-            batch=cfg.batch, stable=True, placement=placement,
-            emit_l=emit_l)
+        fn = (lambda key, positions=None, emit_l=0, with_steps=False:
+              _sparse_loop(key, src, dst, prob, positions, n_nodes=graph.n,
+                           batch=cfg.batch, stable=True, placement=placement,
+                           emit_l=emit_l, with_steps=with_steps))
     else:
-        fn = lambda key, emit_l=0: _sparse_loop(
+        fn = lambda key, emit_l=0, with_steps=False: _sparse_loop(
             key, src, dst, prob, n_nodes=graph.n, batch=cfg.batch,
-            placement=placement, emit_l=emit_l)
+            placement=placement, emit_l=emit_l, with_steps=with_steps)
     # the engine routes C4 per-backend through this tag: an IndexStore
     # asks a tagged sampler for native index rows (`emit_l`) instead of
     # densifying to bitmaps and converting at the arena write
     fn.supports_index_emit = True
-    return fn
+    # one coin per (row, edge) a step, pad edges included
+    return _states_work(fn, cfg.batch * int(src.shape[0]), graph, cfg)
 
 
 def _bind_walk(model, graph: Graph, cfg, *, stable, placement):
     tables = model.walk_tables(graph)
     if stable:
-        return lambda key, positions=None: _walk_loop(
+        fn = lambda key, positions=None, with_steps=False: _walk_loop(
             key, *tables, positions, batch=cfg.batch, stable=True,
-            placement=placement)
-    return lambda key: _walk_loop(
-        key, *tables, batch=cfg.batch, placement=placement)
+            placement=placement, with_steps=with_steps)
+    else:
+        fn = lambda key, with_steps=False: _walk_loop(
+            key, *tables, batch=cfg.batch, placement=placement,
+            with_steps=with_steps)
+    # one uniform per row a step
+    return _states_work(fn, cfg.batch, None, cfg)
 
 
 DENSE_BACKEND = TraversalBackend("dense", "coins", _bind_dense)

@@ -315,12 +315,19 @@ class _ArenaBase:
             new_cap = min(new_cap, max(cap, self.capacity))
         if new_cap == self.capacity:
             return
-        self._realloc(new_cap)
-        sizes = jnp.zeros((new_cap,), jnp.int32)
-        self.sizes = _write_rows(sizes, self.sizes, jnp.int32(0))
-        self.live = jnp.concatenate(
-            [self.live, jnp.ones((new_cap - self.capacity,), jnp.bool_)])
-        self.capacity = new_cap
+        old = self.capacity
+        # bytes from shapes: the new arena and sizes are filled whole and
+        # the old rows read and written into them; live is concatenated
+        with obs.span("store.grow", tier="store", old_capacity=old,
+                      new_capacity=new_cap,
+                      bytes=(new_cap + 2 * old) * (self._row_bytes() + 4)
+                      + new_cap + old):
+            self._realloc(new_cap)
+            sizes = jnp.zeros((new_cap,), jnp.int32)
+            self.sizes = _write_rows(sizes, self.sizes, jnp.int32(0))
+            self.live = jnp.concatenate(
+                [self.live, jnp.ones((new_cap - old,), jnp.bool_)])
+            self.capacity = new_cap
 
     def _finish_add(self, batch_sizes, counter):
         B = batch_sizes.shape[0]
@@ -1403,7 +1410,14 @@ class ShardedStore:
         grow = _sharded_grow_kernel(
             self.mesh, self.theta_axes, self.vertex_axis,
             new_cap - self.cap_local)
-        self.R, self.sizes, self.live = grow(self.R, self.sizes, self.live)
+        # bytes from shapes: each tile pads its arena, sizes and live
+        # rows, reading the old rows and writing the new capacity
+        old, new = self.D * self.cap_local, self.D * new_cap
+        with obs.span("store.grow", tier="store", old_capacity=old,
+                      new_capacity=new,
+                      bytes=(new + old) * (self._row_bytes() + 4 + 1)):
+            self.R, self.sizes, self.live = grow(self.R, self.sizes,
+                                                 self.live)
         # shard blocks moved apart: global slot d*cap_local+i is now
         # d*new_cap+i — record the renumbering for provenance trackers
         old_cap = self.cap_local

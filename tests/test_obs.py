@@ -308,3 +308,81 @@ def test_obs_on_off_bitwise_identical_single_device():
     assert snap["counters"]["store.rows_written"] == r_on.theta
     assert snap["gauges"]["engine.theta"]["value"] == r_on.theta
     assert 0.0 < snap["gauges"]["store.occupancy"]["value"] <= 1.0
+
+
+# ------------------------------------------- clock, device values, gc
+
+
+def test_span_ts_is_on_the_realtime_clock():
+    """``ts`` is microseconds since the Unix epoch (the clock a JAX
+    profile stamps its events with), at ``perf_counter`` resolution."""
+    import time
+    obs.enable()
+    before = time.time_ns()
+    with obs.span("s"):
+        pass
+    after = time.time_ns()
+    ev = obs.get_tracer().events("s")[0]
+    assert before - 1e6 <= ev["ts"] * 1e3 <= after + 1e6
+
+
+def test_device_scalars_are_held_and_resolved_once_at_export():
+    import jax.numpy as jnp
+    obs.enable()
+    steps = jnp.int32(7)
+    with obs.span("sample", tier="engine") as sp:
+        sp.set(steps=steps, coins=obs.Deferred(steps, 2**31), sets=3)
+    tr = obs.get_tracer()
+    assert len(tr._held) == 1
+    raw = tr._events[0]["args"]
+    assert raw["steps"] is steps                  # held, not read
+    ev = [e for e in obs.chrome_trace()["traceEvents"] if e["ph"] == "X"][0]
+    assert ev["args"]["steps"] == 7 and type(ev["args"]["steps"]) is int
+    assert ev["args"]["coins"] == 7 * 2**31       # a Python int past 2**31
+    assert ev["args"]["sets"] == 3
+    assert not tr._held
+    json.dumps(obs.chrome_trace())                # resolved for good
+    assert tr.events("sample")[0]["args"]["coins"] == 7 * 2**31
+
+
+def test_disabled_span_enters_as_none_and_holds_nothing():
+    with obs.span("sample", tier="engine") as sp:
+        assert sp is None
+    g = rmat_graph(96, 512, seed=2)
+    eng = InfluenceEngine(g, IMMConfig(k=4, batch=64, max_theta=128,
+                                       seed=3))
+    eng.extend(128)
+    tr = obs.get_tracer()
+    assert len(tr) == 0 and not tr._held
+    assert eng.store.count == 128
+
+
+def test_dropped_events_release_their_held_values():
+    import jax.numpy as jnp
+    obs.enable(tracer=obs.Tracer(max_events=2))
+    for i in range(5):
+        with obs.span("s") as sp:
+            sp.set(v=jnp.int32(i))
+    tr = obs.get_tracer()
+    assert len(tr._held) == 2
+    assert [e["args"]["v"] for e in tr.events()] == [3, 4]
+
+
+def test_gc_spans_follow_the_switch():
+    import gc
+    obs.enable(jax_annotations=True)
+    assert obs._on_gc in gc.callbacks
+    gc.collect()
+    evs = obs.get_tracer().events("host.gc", "host")
+    assert evs and evs[-1]["args"]["generation"] == 2
+    assert evs[-1]["args"]["collected"] >= 0 and evs[-1]["dur"] >= 0
+    obs.disable()
+    assert obs._on_gc not in gc.callbacks
+    gc.collect()
+    assert len(obs.get_tracer().events("host.gc")) == len(evs)
+    obs.enable()                      # the bridged tracer is kept
+    assert obs._on_gc in gc.callbacks
+    obs.reset()
+    assert obs._on_gc not in gc.callbacks
+    obs.enable()                      # no bridge, no gc spans
+    assert obs._on_gc not in gc.callbacks
