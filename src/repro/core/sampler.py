@@ -31,9 +31,10 @@ models once they are separated from the traversal loop):
         mat-vec* on the dense activation matrix: P(u activated by
         frontier F) = 1 - prod_{v in F} (1 - p_{u->v-reversed}); exact in
         distribution for reachability (DESIGN §2). MXU-friendly.
-      - ``sparse`` — per-edge Bernoulli coins + scatter-max frontier
-        expansion over the CSC edge list; exact live-edge semantics,
-        scales to graphs where the dense matrix does not fit.
+      - ``sparse`` — per-edge Bernoulli coins, each vertex pulling the
+        OR of its out-edges' live heads over the source-sorted edge
+        list; exact live-edge semantics, scales to graphs where the
+        dense matrix does not fit.
       - ``pallas`` — the dense formulation with the frontier step
         executed by the fused Pallas MXU kernel
         ``kernels/ic_frontier.py`` (matmul + Bernoulli sampling + visited
@@ -65,15 +66,15 @@ batch roots (the sparse backend can alternatively emit index lists
 natively — C4 routed per-backend, see ``emit_l``).  Factories accept an
 optional ``placement`` (a ``jax.sharding.NamedSharding`` for the
 ``(B, n)`` output — a `ShardedStore` hands out its ``batch_sharding``):
-the constraint is applied to the initial frontier state inside jit, so
-GSPMD partitions the whole generation loop over the batch axis — and,
-when the placement is 2D (``P(theta_axes, vertex_axis)``), over the
-vertex axis too: each device samples exactly the (row block, vertex
-block) tile its arena shard will store (paper C1, both axes).  The coin
-backends additionally pin their graph tables to the same vertex blocks
-(`_shard_cols`): the dense ``logq`` matrix is column-partitioned so each
-device expands only its own vertex block from the all-gathered frontier
-— the frontier exchange is the only cross-shard traffic in the loop.
+the generation loop is partitioned over the batch axis — and, when the
+placement is 2D (``P(theta_axes, vertex_axis)``), over the vertex axis
+too: each device samples exactly the (row block, vertex block) tile its
+arena shard will store (paper C1, both axes).  The dense backends pin
+their ``logq`` matrix to the same vertex blocks (`_shard_cols`), and the
+sparse loop runs per device over a window of its edges
+(`_sparse_loop`), so each device expands only its own vertex block from
+the all-gathered frontier — the frontier exchange is the only
+cross-shard traffic in the loop.
 PRNG values are position- or identity-keyed, so placement changes layout
 only — sampled sets are bitwise identical on any mesh shape.
 """
@@ -88,8 +89,10 @@ from typing import Callable
 import numpy as np
 import jax
 import jax.numpy as jnp
+from jax.extend.random import threefry2x32_p
 from jax.sharding import NamedSharding, PartitionSpec
 
+from repro.compat import shard_map
 from repro.core.adaptive import bitmap_to_indices
 from repro.core.store import next_pow2
 from repro.graphs.csr import Graph, dense_ic_matrix, edge_arrays, wc_edge_probs
@@ -109,9 +112,7 @@ _LOGQ_CLAMP = -30.0  # exp(-30) ~ 1e-13: treat p=1 edges as prob 1-1e-13
 # for trailing-dim shardings): the dense ``logq`` matrix becomes
 # column-blocked, so each device computes activations only for its own
 # vertex block from the all-gathered frontier — the frontier exchange is
-# the only cross-shard traffic in the loop — and the CSC edge arrays
-# become contiguous dst-block slabs (CSC order is dst-sorted, so an even
-# split of the edge list approximates the dst blocks).  PRNG values are
+# the only cross-shard traffic in the loop.  PRNG values are
 # position- or identity-keyed, so all of this changes layout only: the
 # sampled sets stay bitwise identical on any mesh shape.
 
@@ -126,7 +127,7 @@ def _vertex_axis_of(placement):
 def _shard_cols(x, placement):
     """Constrain a graph table's trailing axis to the placement's vertex
     axis (no-op for 1D/absent placements): ``(n, n)`` tables become
-    column-blocked, ``(m,)``/``(n,)`` tables contiguous slabs."""
+    column-blocked."""
     vx = _vertex_axis_of(placement)
     if vx is None:
         return x
@@ -281,6 +282,45 @@ def _u01(bits):
 _GOLD = 0x9E3779B9   # 2**32 / phi — the classic Weyl increment
 
 
+# ---------------------------------------------- counter-mode positional coins ----
+#
+# ``jax.random.uniform(s, shape)`` under JAX's partitionable threefry
+# (the default since JAX 0.5) is a pure function of each element's flat
+# index: element ``i`` hashes the 64-bit counter ``i`` with
+# ``threefry2x32(s, (hi, lo) of i)``, XORs the two output words, and keeps
+# the top 23 bits as a float in [0, 1).  Drawing the same counters in
+# another layout therefore gives the same coins bit for bit, which is what
+# lets the sparse loop hold its coins edge-major without renumbering one.
+
+def _counter_base(batch: int, m: int):
+    """``(hi, lo)`` uint32 words of each row's first counter ``b * m``,
+    ``(batch,)`` each, split exactly on the host."""
+    base = np.arange(batch, dtype=np.uint64) * np.uint64(m)
+    return ((base >> np.uint64(32)).astype(np.uint32),
+            (base & np.uint64(0xFFFFFFFF)).astype(np.uint32))
+
+
+def _counter_words(hi_b, lo_b, pos):
+    """``(hi, lo)`` words of the 64-bit counters ``base[b] + pos[j]``,
+    each ``(len(pos), len(base))``: ``pos`` carries into ``hi`` where
+    ``lo`` wraps.  With `_counter_base`'s words that is element ``(j,
+    b)`` of ``(batch, m)``'s flat index ``b * m + pos[j]``."""
+    lo_b = jnp.asarray(lo_b)[None, :]
+    lo = lo_b + pos.astype(jnp.uint32)[:, None]
+    hi = jnp.asarray(hi_b)[None, :] + (lo < lo_b).astype(jnp.uint32)
+    return hi, lo
+
+
+def _uniform_at(key, hi, lo):
+    """The elements of ``jax.random.uniform(key, shape)`` whose flat
+    indices have the 64-bit words ``(hi, lo)``, in their layout."""
+    kd = jnp.asarray(key, jnp.uint32).reshape(-1)
+    x0, x1 = threefry2x32_p.bind(kd[0], kd[1], hi, lo)
+    bits = (x0 ^ x1) >> jnp.uint32(9) | jnp.uint32(0x3F800000)
+    return (jax.lax.bitcast_convert_type(bits, jnp.float32)
+            - jnp.float32(1.0))
+
+
 def _setup(key, batch, n_nodes, positions, placement, stable):
     """Shared traversal preamble: the (kroot, kstep) split, full-batch
     roots, initial visited state, and (stable only) per-row hash lanes.
@@ -400,20 +440,72 @@ def _dense_loop(key, logq, positions=None, *, batch: int, max_steps: int = 0,
     return out + (steps,) if with_steps else out
 
 
+def _mesh_axes(placement, positions):
+    """``(theta axes, vertex axis)`` the sparse loop splits its lanes and
+    its vertices over: those of a ``(B, n)`` batch placement, or None
+    each when there is none (or a ``positions`` subset, whose rows keep
+    no placement)."""
+    if placement is None or positions is not None:
+        return None, None
+    return tuple(placement.spec)[0], _vertex_axis_of(placement)
+
+
+def _axis_size(mesh, axes) -> int:
+    if axes is None:
+        return 1
+    axes = axes if isinstance(axes, tuple) else (axes,)
+    return int(np.prod([mesh.shape[a] for a in axes]))
+
+
+def _sparse_slab(edge_src, n_nodes: int, placement) -> int:
+    """The CSR edges each device of the sparse loop walks under
+    ``placement``: the most out-edges any block of its vertex axis has
+    (every edge without a vertex axis)."""
+    src = np.asarray(edge_src)
+    vx = _vertex_axis_of(placement)
+    if vx is None:
+        return int(src.shape[0])
+    dv = _axis_size(placement.mesh, vx)
+    return int(np.bincount(src // -(-n_nodes // dv), minlength=dv).max())
+
+
 @partial(jax.jit, static_argnames=("n_nodes", "batch", "max_steps", "stable",
-                                   "placement", "emit_l", "with_steps"))
+                                   "placement", "emit_l", "with_steps",
+                                   "slab", "interpret"))
 def _sparse_loop(key, edge_src, edge_dst, edge_prob, positions=None, *,
                  n_nodes: int, batch: int, max_steps: int = 0,
                  stable: bool = False, placement=None, emit_l: int = 0,
-                 with_steps: bool = False):
-    """CSC edge-list frontier expansion (the ``sparse`` backend).
+                 with_steps: bool = False, csr=None, slab: int = 0,
+                 interpret: bool = False):
+    """Edge-list frontier expansion as a pull (the ``sparse`` backend).
 
     An edge ``u -> v`` is consulted when ``v`` is in the reverse
     frontier (each vertex fronts at most once, so each edge gets exactly
-    one coin — independent-inclusion triggering, any `CoinModel`).
-    Stable coins key on the edge's *identity* ``u * n + v`` rather than
-    its list position, so inserts/deletes renumber nothing; padded
-    never-firing edges (see `_pad_edges_pow2`) are likewise invisible.
+    one coin — independent-inclusion triggering, any `CoinModel`).  The
+    loop holds ``frontier`` and ``visited`` as ``(n, K)``, vertices on
+    rows and the batch's rows on lanes, and walks the edges in CSR order
+    (``csr``: the CSC edge positions stably sorted by source, derived
+    here when absent).  Each step reads every edge's head frontier row,
+    ``frontier[dst]`` (a row gather), keeps the edges whose coin fires,
+    and ORs each vertex's contiguous run of out-edges into it
+    (`repro.kernels.ops.segment_or`): no scatter and no transpose inside
+    the loop.  The state is transposed to ``(K, n)`` once, after it.
+
+    Coins are those of the CSC layout, drawn where the edge sits:
+    positional coins are element ``(b, csc position)`` of ``uniform(s,
+    (batch, m))``, generated by counter (`_uniform_at`); stable coins key
+    on the edge's *identity* ``u * n + v`` rather than its list
+    position, so inserts/deletes renumber nothing; padded never-firing
+    edges (see `_pad_edges_pow2`) are likewise invisible.
+
+    Under a placement the loop runs per device (``shard_map``): lanes
+    split over the theta axes, vertex rows over the vertex axis.  Each
+    device gathers the frontier along the vertex axis once a step (the
+    frontier exchange, the loop's one collective besides the stop test)
+    and pulls only into its own vertex block, over a window of ``slab``
+    CSR edges (`_sparse_slab`; all ``m`` when absent) that holds the
+    block's out-edges — the others in the window never fire — so the
+    reduction stays local.
 
     ``emit_l > 0`` emits the batch *natively as index lists* ``(K,
     emit_l) int32`` (ascending, sentinel ``n_nodes``) instead of
@@ -432,39 +524,84 @@ def _sparse_loop(key, edge_src, edge_dst, edge_prob, positions=None, *,
     """
     m = edge_src.shape[0]
     max_steps = max_steps or n_nodes
-    # 2D placement: slab the CSC edge arrays over the vertex axis (CSC
-    # order is dst-sorted, so contiguous slabs track the dst blocks)
-    edge_src = _shard_cols(edge_src, placement)
-    edge_dst = _shard_cols(edge_dst, placement)
-    edge_prob = _shard_cols(edge_prob, placement)
-    kstep, roots, visited0, bb = _setup(
-        key, batch, n_nodes, positions, placement, stable)
-    uid = ((edge_src.astype(jnp.uint32) * jnp.uint32(n_nodes)
-            + edge_dst.astype(jnp.uint32))[None, :] if stable else None)
+    if csr is None:
+        csr = jnp.argsort(edge_src, stable=True)
+    csr = csr.astype(jnp.int32)
+    theta, vx = _mesh_axes(placement, positions)
+    mesh = placement.mesh if placement is not None else None
+    axes = tuple(x for ax in (theta, vx) if ax is not None
+                 for x in (ax if isinstance(ax, tuple) else (ax,)))
+    dt, dv = _axis_size(mesh, theta), _axis_size(mesh, vx)
+    slab = slab if vx is not None and slab else m
+    kstep, roots, _, bb = _setup(key, batch, n_nodes, positions, None,
+                                 stable)
+    K = roots.shape[0]
+    kl, nl = -(-K // dt), -(-n_nodes // dv)     # lanes, rows per device
+    hi_b, lo_b = _counter_base(kl * dt, m)
+    lane_args = (jnp.pad(roots, (0, kl * dt - K), constant_values=-1),)
+    if stable:
+        lane_args += (jnp.pad(bb[:, 0], (0, kl * dt - K)),)
 
-    def cond(state):
-        step, frontier, visited, _ = state
-        return jnp.logical_and(step < max_steps, frontier.any())
-
-    def body(state):
-        step, frontier, visited, k = state
-        k, sub = jax.random.split(k)
+    def shard(src, dst, prob, pos, roots, bb=None):
+        i = jax.lax.axis_index(theta) if theta is not None else 0
+        j = jax.lax.axis_index(vx) if vx is not None else 0
+        # this device's vertex block [j * nl, (j + 1) * nl): a window of
+        # the CSR edges that holds its out-edges; the rest never fire
+        at = jnp.clip(jnp.searchsorted(src, j * nl), 0, m - slab)
+        win = lambda x: jax.lax.dynamic_slice_in_dim(x, at, slab)
+        src, dst, pos = win(src), win(dst), win(pos)
+        mine = (src >= j * nl) & (src < (j + 1) * nl)
+        prob = jnp.where(mine, win(prob), 0.0)[:, None]
+        lanes = lambda x: jax.lax.dynamic_slice_in_dim(x, i * kl, kl)
         if stable:
-            kd = jnp.asarray(sub, jnp.uint32).reshape(-1)
-            coin = _u01(_mix32(_mix32(uid ^ kd[0]) ^ bb ^ kd[1]))
-            hit = coin < edge_prob[None, :]
+            uid = (src.astype(jnp.uint32) * jnp.uint32(n_nodes)
+                   + dst.astype(jnp.uint32))[:, None]
+            bb = lanes(bb)[None, :]
         else:
-            hit = jax.random.uniform(sub, (batch, m)) < edge_prob[None, :]
-        # reverse traversal: edge u->v is usable when v is in the frontier
-        live = frontier[:, edge_dst] & hit & ~visited[:, edge_src]
-        # scatter-or into src — the segment_max counter-update pattern (C1)
-        new = jnp.zeros_like(visited).at[:, edge_src].max(live)
-        new = jnp.logical_and(new, ~visited)
-        return step + 1, new, jnp.logical_or(visited, new), k
+            words = _counter_words(lanes(jnp.asarray(hi_b)),
+                                   lanes(jnp.asarray(lo_b)), pos)
+        src = jnp.clip(src - j * nl, 0, nl - 1)
+        visited0 = ((jnp.arange(nl, dtype=jnp.int32) + j * nl)[:, None]
+                    == lanes(roots)[None, :])
 
-    steps, _, visited, _ = jax.lax.while_loop(
-        cond, body, (jnp.int32(0), visited0, visited0, kstep)
-    )
+        def cond(state):
+            step, frontier, visited, _ = state
+            more = frontier.any().astype(jnp.int32)
+            if axes:
+                more = jax.lax.psum(more, axes)
+            return jnp.logical_and(step < max_steps, more > 0)
+
+        def body(state):
+            step, frontier, visited, key = state
+            key, sub = jax.random.split(key)
+            if stable:
+                kd = jnp.asarray(sub, jnp.uint32).reshape(-1)
+                coin = _u01(_mix32(_mix32(uid ^ kd[0]) ^ bb ^ kd[1]))
+            else:
+                coin = _uniform_at(sub, *words)
+            if vx is not None:
+                # the frontier exchange: an edge's head may be anywhere
+                frontier = jax.lax.all_gather(frontier, vx, tiled=True)
+            # reverse traversal: edge u->v is live when v is in the
+            # frontier; u takes the OR of its contiguous out-edges
+            live = frontier[dst] & (coin < prob)
+            new = kops.segment_or(live, src, n=nl, interpret=interpret)
+            new = jnp.logical_and(new, ~visited)
+            return step + 1, new, jnp.logical_or(visited, new), key
+
+        steps, _, visited, _ = jax.lax.while_loop(
+            cond, body, (jnp.int32(0), visited0, visited0, kstep))
+        return visited, steps
+
+    args = (edge_src[csr], edge_dst[csr], edge_prob[csr], csr) + lane_args
+    if axes:
+        rep = PartitionSpec()
+        shard = shard_map(shard, mesh=mesh, in_specs=(rep,) * len(args),
+                          out_specs=(PartitionSpec(vx, theta), rep))
+    visited, steps = shard(*args)
+    visited = visited[:n_nodes, :K].T
+    if axes:
+        visited = jax.lax.with_sharding_constraint(visited, placement)
     counter = visited.sum(axis=0, dtype=jnp.int32)
     if emit_l:
         out = (bitmap_to_indices(visited.astype(jnp.uint8), emit_l),
@@ -698,14 +835,20 @@ def _bind_sparse(model, graph: Graph, cfg, *, stable, placement):
         # positional sampler keeps the exact edge count (seed parity
         # with the historical IC-sparse stream)
         src, dst, prob = _pad_edges_pow2(src, dst, prob)
+    # the CSR order the loop walks its edges in, sorted once per graph,
+    # and the window of it each vertex block of the placement walks
+    csr = np.argsort(np.asarray(src), kind="stable").astype(np.int32)
+    kw = dict(n_nodes=graph.n, batch=cfg.batch, placement=placement,
+              csr=jnp.asarray(csr),
+              slab=_sparse_slab(src, graph.n, placement),
+              interpret=bool(getattr(cfg, "pallas_interpret", False)))
+    if stable:
         fn = (lambda key, positions=None, emit_l=0, with_steps=False:
-              _sparse_loop(key, src, dst, prob, positions, n_nodes=graph.n,
-                           batch=cfg.batch, stable=True, placement=placement,
-                           emit_l=emit_l, with_steps=with_steps))
+              _sparse_loop(key, src, dst, prob, positions, stable=True,
+                           emit_l=emit_l, with_steps=with_steps, **kw))
     else:
         fn = lambda key, emit_l=0, with_steps=False: _sparse_loop(
-            key, src, dst, prob, n_nodes=graph.n, batch=cfg.batch,
-            placement=placement, emit_l=emit_l, with_steps=with_steps)
+            key, src, dst, prob, emit_l=emit_l, with_steps=with_steps, **kw)
     # the engine routes C4 per-backend through this tag: an IndexStore
     # asks a tagged sampler for native index rows (`emit_l`) instead of
     # densifying to bitmaps and converting at the arena write

@@ -38,6 +38,7 @@ from repro.kernels.fm_interaction import fm_interaction as _fm_pallas
 from repro.kernels.flash_attention import flash_attention as _flash_pallas
 from repro.kernels.packed_count import packed_count as _packed_count_pallas
 from repro.kernels.packed_count import token_count as _token_count_pallas
+from repro.kernels.segment_or import segment_or as _segment_or_pallas
 
 
 def _on_tpu() -> bool:
@@ -79,6 +80,12 @@ def ic_frontier_step(frontier, visited, logq, rand, *, interpret=False,
         return _frontier_pallas(frontier, visited, logq, rand,
                                 interpret=interpret, **kw)
     return ref.ic_frontier_ref(frontier, visited, logq, rand).astype("uint8")
+
+
+def segment_or(live, src, *, n, interpret=False, **kw):
+    if _dispatch("segment_or", interpret):
+        return _segment_or_pallas(live, src, n=n, interpret=interpret, **kw)
+    return ref.segment_or_ref(live, src, n)
 
 
 def arena_commit(rows, *, kind="bitmap", interpret=False, **kw):

@@ -37,6 +37,13 @@ def ic_frontier_ref(frontier, visited, logq, rand):
     return jnp.logical_and(rand < p_act, ~visited)
 
 
+def segment_or_ref(live, src, n):
+    """live: (m, B) 0/1 rows, src: (m,) int32 sorted row owners ->
+    (n, B) bool, the OR of each owner's rows (False where it has none)."""
+    return jax.ops.segment_max(live.astype(jnp.int8), src, num_segments=n,
+                               indices_are_sorted=True) > 0
+
+
 def fm_interaction_ref(v):
     """FM 2-way interaction via the O(nk) sum-square trick (Rendle ICDM'10).
 

@@ -54,6 +54,9 @@ def main(argv=None):
                     help="at-rest arena format for the sharded engine "
                          "('auto' = bitmap tiles; 'packed'/'compressed' "
                          "run the IMPack codecs on every mesh tile)")
+    ap.add_argument("--backend", default=None, choices=("dense", "sparse"),
+                    help="traversal backend of every engine (default: "
+                         "the engine's choice, dense at this n)")
     args = ap.parse_args(argv)
 
     mesh = make_im_mesh(args.mesh)
@@ -65,7 +68,7 @@ def main(argv=None):
 
     g = rmat_graph(128, 1024, seed=4)
     cfg = IMMConfig(k=5, batch=64, max_theta=256, seed=3,
-                    store=args.store)
+                    store=args.store, backend=args.backend)
     # the reference stays a single-device bitmap: the IMPack formats
     # must match IT, not just each other
     cfg_dense = dataclasses.replace(cfg, store="auto")
@@ -212,6 +215,7 @@ def main(argv=None):
     print(json.dumps({
         "ok": True, "devices": n_dev, "mesh": args.mesh,
         "store": args.store,
+        "sampler": sharded.sampler_name,
         "theta": int(r_sharded.theta),
         "cap_local": int(st.cap_local), "n_local": int(st.n_local),
         "counts": [int(c) for c in st.counts],

@@ -176,3 +176,38 @@ def test_arena_commit_tilings(kind):
     ref_s, ref_c = ref.arena_commit_ref(rows, kind=kind)
     np.testing.assert_array_equal(np.asarray(got_s), np.asarray(ref_s))
     np.testing.assert_array_equal(np.asarray(got_c), np.asarray(ref_c))
+
+
+# ---------------------------------------------------------- segment_or ----
+# The sparse sampler's pull reduction: bitwise against the sorted
+# segment_max oracle, on skewed segments (long runs crossing many edge
+# tiles, empty vertex blocks, a last tile cut short).
+
+def _segments(n, m, seed):
+    rng = np.random.default_rng(seed)
+    deg = rng.zipf(1.6, size=n).astype(np.float64)
+    deg[rng.random(n) < 0.4] = 0             # vertices with no out-edges
+    src = np.repeat(np.arange(n), np.floor(deg * m / deg.sum()).astype(int))
+    src = np.sort(np.concatenate([src, rng.integers(0, n, m - src.size)]))
+    return jnp.asarray(src[:m], jnp.int32)
+
+
+@pytest.mark.parametrize("n,m,B", [(300, 2000, 128), (1000, 500, 256),
+                                   (50, 4000, 128), (7, 9, 128)])
+def test_segment_or_bitwise(n, m, B):
+    src = _segments(n, m, n + m)
+    live = jax.random.uniform(jax.random.PRNGKey(m), (m, B)) < 0.05
+    got = ops.segment_or(live, src, n=n, interpret=True)
+    np.testing.assert_array_equal(np.asarray(got),
+                                  np.asarray(ref.segment_or_ref(live, src, n)))
+
+
+@pytest.mark.parametrize("tile_v,tile_e", [(8, 128), (128, 256), (512, 512)])
+def test_segment_or_tilings(tile_v, tile_e):
+    n, m = 600, 3000
+    src = _segments(n, m, 7)
+    live = jax.random.uniform(jax.random.PRNGKey(1), (m, 128)) < 0.01
+    got = ops.segment_or(live, src, n=n, interpret=True, tile_v=tile_v,
+                         tile_e=tile_e)
+    np.testing.assert_array_equal(np.asarray(got),
+                                  np.asarray(ref.segment_or_ref(live, src, n)))

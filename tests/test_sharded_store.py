@@ -519,7 +519,7 @@ def test_make_im_mesh_and_engine_kwargs():
 
 # ---------------------------------------- forced multi-device subprocess ----
 
-def _run_force_mesh(devices: int, mesh: str):
+def _run_force_mesh(devices: int, mesh: str, *extra: str):
     env = dict(os.environ)
     env["PYTHONPATH"] = os.path.join(REPO, "src")
     # drop any inherited device-count flag (the CI mesh pass exports =4;
@@ -532,7 +532,7 @@ def _run_force_mesh(devices: int, mesh: str):
         + inherited).strip()
     r = subprocess.run(
         [sys.executable, os.path.join(REPO, "tests", "force_mesh_check.py"),
-         "--mesh", mesh],
+         "--mesh", mesh, *extra],
         env=env, capture_output=True, text=True, timeout=540)
     assert r.returncode == 0, r.stdout[-2000:] + r.stderr[-2000:]
     return json.loads(r.stdout.strip().splitlines()[-1])
@@ -556,3 +556,13 @@ def test_sharded_store_forced_8dev_2x4_subprocess():
     out = _run_force_mesh(8, "2x4")
     assert out["ok"] and out["devices"] == 8
     assert out["n_local"] == 32        # ceil(128 / 4) vertex columns
+
+
+def test_sparse_sampler_forced_4dev_2x2_subprocess():
+    """The sparse backend's per-device pull on a forced-4-device 2x2
+    mesh: each device pulls into its own vertex block over its window of
+    the CSR edges, and every answer stays bitwise the single-device
+    engine's (balanced blocks, overlap off, fused chain off included)."""
+    out = _run_force_mesh(4, "2x2", "--backend", "sparse")
+    assert out["ok"] and out["devices"] == 4
+    assert out["sampler"] == "IC/sparse" and out["n_local"] == 64

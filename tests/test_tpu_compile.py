@@ -17,6 +17,7 @@ import os
 import pytest
 
 N = 334_863          # com-Amazon |V|
+M = 1_851_744        # com-Amazon's directed edges (925,872 both ways)
 THETA = 16_384       # the default --max-theta
 B = 256              # IMMConfig.batch
 HBM_BYTES = 16 * 2**30
@@ -55,6 +56,7 @@ def _kernel_case(name, one_chip):
     from repro.kernels.fused_select import fused_select
     from repro.kernels.ic_frontier import ic_frontier_step
     from repro.kernels.packed_count import packed_count, token_count
+    from repro.kernels.segment_or import segment_or
 
     S = lambda shape, dt: _spec(one_chip, shape, dt)    # noqa: E731
     words = -(-N // 8)
@@ -79,6 +81,8 @@ def _kernel_case(name, one_chip):
         "token_count_s512": (lambda t, a: token_count(t, a, n=N),
                              [S((THETA, 512), jnp.int32),
                               S((THETA,), jnp.float32)]),
+        "segment_or": (lambda live, src: segment_or(live, src, n=N),
+                       [S((M, B), jnp.bool_), S((M,), jnp.int32)]),
         "ic_frontier_n4096": (ic_frontier_step,
                               [S((B, 4096), jnp.uint8),
                                S((B, 4096), jnp.uint8),
@@ -90,7 +94,7 @@ def _kernel_case(name, one_chip):
 @pytest.mark.parametrize("name", [
     "arena_commit_bitmap", "arena_commit_packed", "coverage_matvec",
     "fused_select", "packed_count", "token_count_s64", "token_count_s512",
-    "ic_frontier_n4096"])
+    "segment_or", "ic_frontier_n4096"])
 def test_kernel_compiles_for_v5e(one_chip, name):
     import jax
     fn, args = _kernel_case(name, one_chip)
@@ -116,3 +120,33 @@ def test_select_dense_fits_v5e(one_chip, method, monkeypatch):
     total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
              + mem.temp_size_in_bytes)
     assert total < HBM_BYTES, f"select_dense needs {total / 2**30:.2f} GiB"
+
+
+@pytest.mark.parametrize("stable", [False, True])
+def test_sparse_ic_loop_fits_v5e(one_chip, stable, monkeypatch):
+    """The sparse IC traversal at com-Amazon's width: its pull step runs
+    the segment_or kernel, holds no (m, B) float coins and no scatter,
+    and the whole loop fits the chip."""
+    import re
+    import jax.numpy as jnp
+    from repro.core.sampler import _sparse_loop
+    from repro.kernels import ops
+
+    monkeypatch.setattr(ops, "_on_tpu", lambda: True)
+    m = 1 << 21 if stable else M       # stable samplers pad m to pow2
+    compiled = _sparse_loop.lower(
+        _spec(one_chip, (2,), jnp.uint32), _spec(one_chip, (m,), jnp.int32),
+        _spec(one_chip, (m,), jnp.int32), _spec(one_chip, (m,), jnp.float32),
+        n_nodes=N, batch=B, stable=stable, with_steps=True,
+        csr=_spec(one_chip, (m,), jnp.int32)).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    assert not re.search(r"= \S+ scatter\(", text)
+    # a float (m, B) array may exist only inside a fusion, never as a
+    # buffer of the loop's own
+    assert not re.search(rf"%\S+ = f32\[{m},{B}\]\S* (fusion|copy)\(",
+                         text)
+    mem = compiled.memory_analysis()
+    total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+             + mem.temp_size_in_bytes)
+    assert total < HBM_BYTES, f"_sparse_loop needs {total / 2**30:.2f} GiB"
