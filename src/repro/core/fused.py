@@ -296,6 +296,10 @@ class _ShardedFused:
             stored, row_sizes, counter_d, work = fn(key, incs)
             if sp is not None:
                 sp.set(**_sample_args(self._sample, self.batch, work))
+                # the edge window each step walks against the edges owned
+                sp.set(**{k: getattr(self._sample, k)
+                          for k in ("walked", "owned")
+                          if hasattr(self._sample, k)})
         with obs.span("store.write", tier="store", kind=s.codec.kind,
                       fused=True) as sp:
             s.R, s.sizes, s._counter, s._counts = commit(

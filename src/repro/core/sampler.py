@@ -794,7 +794,10 @@ class TraversalBackend:
     work: ``coins_per_step``, the uniforms one loop step draws for a
     full batch, and for coin models ``in_degree``, the ``(n,) int32``
     in-degrees, so a batch consults ``colsum . in_degree`` (row, edge)
-    pairs (each member fronts once and tries each in-edge once).
+    pairs (each member fronts once and tries each in-edge once).  The
+    sparse backend's also give ``walked``, the edges the devices of one
+    theta shard walk a step (``Dv x slab`` under a vertex axis, else
+    ``m``), and ``owned``, the ``m`` edges there are.
     """
     name: str
     family: str
@@ -838,9 +841,9 @@ def _bind_sparse(model, graph: Graph, cfg, *, stable, placement):
     # the CSR order the loop walks its edges in, sorted once per graph,
     # and the window of it each vertex block of the placement walks
     csr = np.argsort(np.asarray(src), kind="stable").astype(np.int32)
+    slab = _sparse_slab(src, graph.n, placement)
     kw = dict(n_nodes=graph.n, batch=cfg.batch, placement=placement,
-              csr=jnp.asarray(csr),
-              slab=_sparse_slab(src, graph.n, placement),
+              csr=jnp.asarray(csr), slab=slab,
               interpret=bool(getattr(cfg, "pallas_interpret", False)))
     if stable:
         fn = (lambda key, positions=None, emit_l=0, with_steps=False:
@@ -853,8 +856,13 @@ def _bind_sparse(model, graph: Graph, cfg, *, stable, placement):
     # asks a tagged sampler for native index rows (`emit_l`) instead of
     # densifying to bitmaps and converting at the arena write
     fn.supports_index_emit = True
-    # one coin per (row, edge) a step, pad edges included
-    return _states_work(fn, cfg.batch * int(src.shape[0]), graph, cfg)
+    # the edges one theta shard's devices walk a step, a window of the
+    # CSR edges per vertex block, against the edges there are (pad
+    # edges included): one coin per (row, walked edge) a step
+    fn.owned = int(src.shape[0])
+    fn.walked = _axis_size(getattr(placement, "mesh", None),
+                           _vertex_axis_of(placement)) * slab
+    return _states_work(fn, cfg.batch * fn.walked, graph, cfg)
 
 
 def _bind_walk(model, graph: Graph, cfg, *, stable, placement):

@@ -739,17 +739,24 @@ class IndexStore(_ArenaBase):
 # ------------------------------------------------------- sharded (C1) ----
 
 
+@functools.lru_cache(maxsize=None)
+def _sharded_fill(shape, dtype, sharding, value):
+    """The program that allocates a ``value``-filled array *born
+    sharded* (under jit with ``out_shardings``, so the full logical array
+    is never materialized on a single device); cached, so that a store
+    emptied or built again at the same shapes compiles nothing."""
+    return jax.jit(partial(jnp.full, shape, value, dtype),
+                   out_shardings=sharding)
+
+
 def _sharded_zeros(shape, dtype, sharding):
-    """Zeros *born sharded*: allocated under jit with ``out_shardings`` so
-    the full logical array is never materialized on a single device."""
-    return jax.jit(partial(jnp.zeros, shape, dtype),
-                   out_shardings=sharding)()
+    """Zeros born sharded (`_sharded_fill`)."""
+    return _sharded_fill(tuple(shape), dtype, sharding, 0)()
 
 
 def _sharded_ones(shape, dtype, sharding):
-    """Ones born sharded (see `_sharded_zeros`)."""
-    return jax.jit(partial(jnp.ones, shape, dtype),
-                   out_shardings=sharding)()
+    """Ones born sharded (`_sharded_fill`)."""
+    return _sharded_fill(tuple(shape), dtype, sharding, 1)()
 
 
 def _psum_if(x, axis):
@@ -1196,7 +1203,6 @@ class ShardedStore:
                 np.clip(src, 0, max(self.n - 1, 0)), jnp.int32)
             self._col_ok = jnp.asarray((src < self.n).astype(np.uint8))
             self._cols_from_pad = partition.padded_cols()
-        self._counts_host = np.zeros((self.D,), np.int64)
         if policy is not None:
             cap = policy.row_cap(self._row_bytes())
             if cap // self.D < 1:
@@ -1204,7 +1210,21 @@ class ShardedStore:
                     f"policy row cap {cap} is below one row per shard "
                     f"(D={self.D})")
             self.cap_local = min(self.cap_local, cap // self.D)
+        # what `reset` returns to
+        self._empty_as = (self.cap_local, self.codec)
+        self._bind_kernels()
+        self._alloc_empty()
+
+    def _alloc_empty(self):
+        """An empty arena at the current capacity and codec, born
+        sharded, with its host mirrors."""
+        self._counts_host = np.zeros((self.D,), np.int64)
         self._live_host = np.ones((self.D * self.cap_local,), bool)
+        # (first set, rows per shard, shard counts before) of each write
+        # since the arena was emptied, in write order: where `_set_slots`
+        # finds a set; None once rows have moved or been overwritten
+        self._writes = []
+        self._written = 0
         self.R = _sharded_zeros(
             (self.D * self.cap_local, self.w_pad), self.codec.dtype,
             self._sh_rows)
@@ -1215,8 +1235,27 @@ class ShardedStore:
         self._counter = _sharded_zeros(
             (self.D, self.n_pad), jnp.int32, self._sh_rows)
         self._counts = _sharded_zeros((self.D,), jnp.int32, self._sh_vec)
-        self._bind_kernels()
         self._idx_cache = None      # (version, l_pad) -> sharded R_idx
+
+    def reset(self) -> None:
+        """Empty the arena in place: capacity and codec back to those it
+        was built with, every row, size, count and counter partial zero,
+        as a fresh store on the same mesh and partition.  The object
+        stays the one the engine and its fused extender hold, so the
+        next IMM run on the engine samples into it with the programs it
+        has compiled.  The version moves on, never back, so no answer
+        memoized over the old rows is served over the new ones."""
+        cap_local, codec = self._empty_as
+        self.R = None               # free the old tiles first
+        self.cap_local = cap_local
+        if codec != self.codec:
+            self.codec = codec
+            self.w_local = codec.width
+            self.w_pad = self.Dv * self.w_local
+            self._bind_kernels()
+        self._remaps = []
+        self._alloc_empty()
+        self.version += 1
 
     def _bind_kernels(self):
         """(Re)bind the per-(mesh, axes, codec) compiled kernels — called
@@ -1433,6 +1472,14 @@ class ShardedStore:
             self._remaps.append(remap)
         self.cap_local = new_cap
 
+    def reserve(self, sets: int) -> None:
+        """Grow every shard's tile now, along its pow2 ladder, to hold
+        ``sets`` more sets: the growth the writes would make, made ahead
+        by the same per-shard program, rows and write order unchanged.
+        A caller that knows its target (IMM's theta for a round, or a
+        warm-up of each rung) grows without a write."""
+        self._grow_rows(-(-int(sets) // self.D))
+
     def _ensure_room(self, b: int):
         """Per-shard pressure enforcement: compact away dead rows first,
         then climb the policy's compress ladder (each morph shrinks
@@ -1525,8 +1572,15 @@ class ShardedStore:
         """Host-side bookkeeping after ``B`` rows landed (``count`` is
         derived from ``_counts_host``, so unlike the arena stores only
         the version bump and gauges live here).  Shared by `add_batch`
-        and the fused write chain (`repro.core.fused`)."""
+        and the fused write chain (`repro.core.fused`), which both place
+        row ``r`` of the write in theta shard ``r // ceil(B / D)`` and
+        have advanced the shard counts already."""
         self.version += 1
+        if self._writes is not None:
+            b = -(-B // self.D)
+            incs = np.clip(B - np.arange(self.D) * b, 0, b)
+            self._writes.append((self._written, b, self._counts_host - incs))
+            self._written += B
         if obs.enabled():
             # host arithmetic on shard shapes only — never a device read;
             # byte gauges report *physical* at-rest bytes (the encoded
@@ -1610,6 +1664,7 @@ class ShardedStore:
                 self.R, self._counter, self.sizes, self.live, offs, idx_dev,
                 rows)
             self._live_host[idx[real]] = True
+            self._writes = None
             self.version += 1
         obs.counter("store.rows_replaced").add(k)
 
@@ -1633,6 +1688,7 @@ class ShardedStore:
             remap[lo:lo + self.cap_local][kd] = lo + np.arange(nkeep)
             self._counts_host[d] = nkeep
         self._live_host = np.ones((self.D * self.cap_local,), bool)
+        self._writes = None
         self.version += 1
         obs.counter("store.compactions").add(1)
         if self.track_remaps:
@@ -1640,6 +1696,57 @@ class ShardedStore:
         return remap
 
     # ---------------------------------------------------------- reading ----
+
+    def _set_slots(self, sets) -> np.ndarray:
+        """Global arena slots of the sets numbered ``sets`` in write
+        order since the arena was built or emptied (set ``i`` of a write
+        of ``B`` sets that began at set ``f`` is row ``i - f`` of that
+        batch).  Slots follow growth; after a compaction or a
+        replacement moved or overwrote rows, sets have no number."""
+        if self._writes is None:
+            raise ValueError("rows were compacted or replaced since the "
+                             "arena was emptied: sets have no write order")
+        sets = np.asarray(sets, np.int64).reshape(-1)
+        if sets.size and (sets.min() < 0 or sets.max() >= self._written):
+            raise IndexError(f"sets {sets} outside the {self._written} "
+                             "written since the arena was emptied")
+        first = np.asarray([w[0] for w in self._writes], np.int64)
+        out = np.empty(sets.shape, np.int64)
+        for j, (i, w) in enumerate(zip(
+                sets, np.searchsorted(first, sets, side="right") - 1)):
+            f, b, before = self._writes[w]
+            d, r = divmod(int(i - f), b)
+            out[j] = d * self.cap_local + before[d] + r
+        return out
+
+    def _global_cols(self, R: np.ndarray) -> np.ndarray:
+        """Host rows of the arena, ``(k, w_pad)`` at rest, as ``(k, n)``
+        bit rows in global vertex order: decoded per vertex tile, pad
+        columns stripped, the partition's column layout undone."""
+        if self.codec.kind != "bitmap":
+            R = np.concatenate(
+                [self.codec.decode_np(
+                    R[:, v * self.w_local:(v + 1) * self.w_local])
+                 for v in range(self.Dv)], axis=1)
+        return (R[:, :self.n] if self.partition.is_equal
+                else R[:, self._cols_from_pad])
+
+    def read_sets(self, sets) -> np.ndarray:
+        """The sets numbered ``sets`` (`_set_slots`) as ``(k, n) uint8``
+        host rows in global vertex order, equal to the rows a
+        `BitmapStore` fed the same batches holds at those indices.  Each
+        device gives up only the requested rows of its own tile."""
+        slots = self._set_slots(sets)
+        out = np.zeros((slots.size, self.w_pad), self.codec.dtype)
+        for sh in self.R.addressable_shards:
+            rows, cols = sh.index
+            lo = rows.start or 0
+            hi = self.R.shape[0] if rows.stop is None else rows.stop
+            pick = (slots >= lo) & (slots < hi)
+            if pick.any():
+                out[pick, cols] = np.asarray(
+                    sh.data[jnp.asarray(slots[pick] - lo)])
+        return self._global_cols(out)
 
     def valid_mask(self) -> jnp.ndarray:
         """Sharded ``(D * cap_local,) bool`` mask of filled *live* rows
@@ -1732,14 +1839,7 @@ class ShardedStore:
         always the *bit* interchange format, so any at-rest codec
         restores into any other (the ``rep`` tag records the source
         representation for restore-target defaulting)."""
-        R = np.asarray(self.R)
-        if self.codec.kind != "bitmap":
-            R = np.concatenate(
-                [self.codec.decode_np(
-                    R[:, v * self.w_local:(v + 1) * self.w_local])
-                 for v in range(self.Dv)], axis=1)
-        R = (R[:, :self.n] if self.partition.is_equal
-             else R[:, self._cols_from_pad])
+        R = self._global_cols(np.asarray(self.R))
         sizes = np.asarray(self.sizes)
         keep = self._filled_host() & self._live_host
         live_count = int(keep.sum())

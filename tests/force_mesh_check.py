@@ -21,6 +21,9 @@ devices).  Asserts the C1 acceptance criteria:
     strictly lower per-tile edge imbalance;
   * snapshot/restore round-trips across layouts (this mesh -> 1D -> 1
     shard -> none) without changing answers;
+  * sets read back in write order (`ShardedStore.read_sets`) are the
+    single-device arena's rows, and an arena emptied in place
+    (`ShardedStore.reset`) equals a fresh one and fills on;
   * the fused sample->write->count chain (``fused_pipeline="auto"``, the
     default) is bitwise identical to an explicitly-unfused run, and the
     ``fused-rebuild``/``fused-decrement`` selection strategies match
@@ -211,6 +214,32 @@ def main(argv=None):
             np.asarray(dense.store.counter), np.asarray(back.store.counter))
         np.testing.assert_array_equal(
             np.asarray(dense.store.counter), np.asarray(flat.store.counter))
+
+    # --- sets read back in write order; the arena emptied in place ------
+    for part in ("equal", "balanced") if st.Dv > 1 else ("equal",):
+        eng = InfluenceEngine(
+            g, dataclasses.replace(cfg, partition=part), **kw)
+        ref = InfluenceEngine(g, cfg_dense)
+        eng.extend(128)
+        ref.extend(256)
+        want = np.asarray(ref.store.R)
+        np.testing.assert_array_equal(
+            eng.store.read_sets(np.arange(128)), want[:128])
+        es = eng.store
+        es.reset()
+        fresh = ShardedStore(g.n, mesh=es.mesh, theta_axes=es.theta_axes,
+                             vertex_axis=es.vertex_axis,
+                             partition=es.partition, codec=want_rep)
+        for a in ("R", "sizes", "live", "_counter", "_counts", "counts",
+                  "counter"):
+            np.testing.assert_array_equal(np.asarray(getattr(es, a)),
+                                          np.asarray(getattr(fresh, a)))
+        assert (es.count, es.capacity, es.codec) == (
+            0, fresh.capacity, fresh.codec)
+        # the engine keeps sampling its key stream into the emptied arena
+        eng.extend(128)
+        np.testing.assert_array_equal(
+            eng.store.read_sets(np.arange(128)[::-1]), want[128:][::-1])
 
     print(json.dumps({
         "ok": True, "devices": n_dev, "mesh": args.mesh,

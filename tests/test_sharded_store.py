@@ -413,6 +413,65 @@ def test_2d_balanced_store_matches_bitmap():
         np.asarray(bal.rows_touching_cols(verts, vmask)))
 
 
+@pytest.mark.parametrize("layout", ["equal", "balanced"])
+def test_2d_read_sets_and_reset(layout):
+    """Sets read back by their number in write order (batch b, row r is
+    set b * B + r, whatever shard holds it) are the BitmapStore's rows,
+    for batch sizes the shard count does not divide and on both vertex
+    layouts, and stay so when the tiles grow ahead of a write; emptied
+    in place, the store equals a fresh one (arena,
+    sizes, live bits, counter, counts, capacity) with a later version,
+    and fills again from set 0."""
+    rng = np.random.default_rng(21)
+    n, mesh = 49, im_mesh_2d()
+    part = (skewed_partition(n, mesh.shape["vertex"])
+            if layout == "balanced" else None)
+    bs = BitmapStore(n)
+    sh = ShardedStore(n, mesh=mesh, vertex_axis="vertex", partition=part)
+    batches = [(rng.random((B, n)) < 0.2).astype(np.uint8)
+               for B in (24, 7, 64, 10)]
+    for batch in batches:
+        bs.add_batch(jnp.asarray(batch))
+        sh.add_batch(jnp.asarray(batch))
+    want = np.asarray(bs.R[:bs.count])
+    np.testing.assert_array_equal(sh.read_sets(np.arange(bs.count)), want)
+    pick = np.asarray([104, 0, 30, 31, 24, 63])
+    np.testing.assert_array_equal(sh.read_sets(pick), want[pick])
+    with pytest.raises(IndexError):
+        sh.read_sets([bs.count])
+    # grown ahead of a write, by a rung: the sets stay where they were read
+    cap = sh.capacity
+    sh.reserve(cap - sh.count + 1)
+    assert sh.capacity == 2 * cap and sh.count == bs.count
+    np.testing.assert_array_equal(sh.read_sets(np.arange(bs.count)), want)
+
+    version = sh.version
+    sh.reset()
+    fresh = ShardedStore(n, mesh=mesh, vertex_axis="vertex", partition=part)
+    for a in ("R", "sizes", "live", "_counter", "_counts", "counts",
+              "counter"):
+        np.testing.assert_array_equal(np.asarray(getattr(sh, a)),
+                                      np.asarray(getattr(fresh, a)))
+    assert (sh.count, sh.capacity) == (0, fresh.capacity)
+    assert sh.version > version
+    sh.add_batch(jnp.asarray(batches[1]))
+    np.testing.assert_array_equal(sh.read_sets(np.arange(7)), batches[1])
+
+
+def test_read_sets_refuses_after_rows_moved():
+    """A compaction moves rows, so sets lose their write order."""
+    n, mesh = 16, im_mesh_2d()
+    sh = ShardedStore(n, mesh=mesh, vertex_axis="vertex")
+    sh.add_batch(jnp.ones((8, n), jnp.uint8))
+    sh.kill_rows(np.arange(sh.capacity) == 0)
+    sh.compact()
+    with pytest.raises(ValueError):
+        sh.read_sets([0])
+    sh.reset()
+    sh.add_batch(jnp.ones((4, n), jnp.uint8))
+    assert sh.read_sets([3]).sum() == n
+
+
 def test_2d_balanced_selection_matches_dense():
     """Balanced-layout sharded selection — rebuild/decrement, dense
     bitmaps AND the C4 sharded-sparse index view — equals single-device
