@@ -446,13 +446,18 @@ class InfluenceEngine:
                           "compressed": "compressed"}[rep]
         strategy = get_selection(method, layout)
         with obs.span("select", tier="engine", k=k, method=method,
-                      layout=layout):
-            seeds, frac, gains = strategy(
+                      layout=layout) as sp:
+            out = strategy(
                 view, k, mesh=self.mesh, theta_axes=self.theta_axes,
                 vertex_axis=self.vertex_axis,
                 partition=getattr(self.store, "partition", None),
                 codec=getattr(self.store, "codec", None),
                 pallas_interpret=cfg.pallas_interpret)
+            # strategies that count the arena rows they read report them
+            rows = getattr(out, "rows", None)
+            if sp is not None and rows is not None:
+                sp.set(rows=rows, theta=int(view.R.shape[0]))
+        seeds, frac, gains = jax.device_get(tuple(out))   # one transfer
         sel = Selection(
             seeds=np.asarray(seeds), covered_frac=float(frac),
             influence=float(frac) * self.graph.n, gains=np.asarray(gains),

@@ -4,12 +4,17 @@ Two strategies, both over bitmap ``R (theta, n) uint8`` or index-list
 ``R_idx (theta, L) int32`` representations:
 
   * ``method="rebuild"``   — EfficientIMM (paper C5 "adaptive counter
-    update"): every round recomputes the counter from the *surviving* sets:
-    ``counter = alive @ R`` — on TPU a masked mat-vec that runs on the MXU
-    (Pallas kernel: kernels/coverage_matvec.py / fused_select.py).
+    update"): after one counter build, each pick updates the counter
+    from whichever is the smaller set of rows — subtract the sets it
+    newly covered, or rebuild from the sets that survive it — so no row
+    is read for nothing.  The dense version (`select_dense`) reads only
+    those rows (Pallas kernels: kernels/coverage_rows.py); the sparse,
+    sharded and fused ones rebuild from every surviving set each pick
+    (``counter = alive @ R``, kernels/coverage_matvec.py /
+    fused_select.py).
   * ``method="decrement"`` — Ripples-faithful baseline: keep a running
     counter and subtract the contribution of the sets covered by the newly
-    selected seed.
+    selected seed, counted over the whole arena.
 
 The two are algebraically identical (property-tested); their cost profiles
 differ exactly as the paper describes — with skewed graphs most sets contain
@@ -42,6 +47,7 @@ the live bits of stale RRR rows and they drop out of the very next
 """
 from __future__ import annotations
 
+import dataclasses
 from functools import partial
 
 import jax
@@ -51,37 +57,96 @@ from jax.sharding import PartitionSpec as P
 from repro.compat import shard_map
 from repro.graphs.partition import vertex_partition
 from repro.kernels import ops as kops
+from repro.kernels.coverage_rows import vertex_ids
 from repro.sparse.scatter import bincount_weighted
 
 
 # ---------------------------------------------------------------- dense ----
 
+@jax.tree_util.register_dataclass
+@dataclasses.dataclass(frozen=True)
+class DenseSelection:
+    """What `select_dense` returns.  It reads as the triple ``(seeds,
+    covered_frac, gains)`` that every selection strategy returns, and
+    also carries ``rows``: the arena rows the call's counter build and
+    updates read, a device scalar."""
+    seeds: jax.Array
+    covered_frac: jax.Array
+    gains: jax.Array
+    rows: jax.Array
+
+    def _triple(self):
+        return self.seeds, self.covered_frac, self.gains
+
+    def __iter__(self):
+        return iter(self._triple())
+
+    def __len__(self):
+        return 3
+
+    def __getitem__(self, i):
+        return self._triple()[i]
+
+
 @partial(jax.jit, static_argnames=("k", "method"))
 def select_dense(R, valid, k: int, method: str = "rebuild"):
-    """R: (theta, n) uint8 bitmaps; valid: (theta,) bool (generated sets).
+    """R: (theta, n) uint8 0/1 bitmaps; valid: (theta,) bool (generated
+    sets).
 
     Single-device (arrays replicated / unsharded); ``valid`` may be any
-    mask.  Every counter rebuild streams the uint8 arena tile by tile
-    through `repro.kernels.ops.coverage_matvec` (Pallas on TPU, the jnp
-    oracle elsewhere), so no widened copy of the arena is ever made.
-    Returns (seeds (k,) int32, covered_frac () f32, gains (k,) int32).
-    """
-    def counter_of(rows):
-        return kops.coverage_matvec(rows.astype(jnp.float32), R)
+    mask.  ``method="rebuild"`` reads the arena once into a set-major
+    row view (`repro.kernels.ops.row_view`, which counts the ``valid``
+    rows in the same pass: the counter build), then updates the counter
+    after each pick but the last from whichever is smaller: the ``c``
+    rows the pick newly covered (subtracted) or the ``a`` rows alive
+    after it (counted afresh), subtracting when ``c <= a``.  Each update
+    reads just those rows of the view (`repro.kernels.ops.coverage_rows`),
+    so a call reads ``theta + sum(min(c, a))`` rows; counts are exact
+    int32, so seeds and gains equal a full rebuild's, first index on
+    ties.
+    ``method="decrement"`` counts the covered rows over the whole arena
+    every pick (`coverage_matvec`, Pallas on TPU, the jnp oracle
+    elsewhere), ``k + 1`` passes.
 
+    Returns a `DenseSelection`: (seeds (k,) int32, covered_frac () f32,
+    gains (k,) int32), and ``rows`` () int32.
+    """
+    theta, n = R.shape
     if method == "rebuild":
+        W, counter = kops.row_view(R, valid)
+        vertex = vertex_ids(W.shape[1])
+
+        def count(mask):
+            return kops.coverage_rows(mask, W), mask.sum(dtype=jnp.int32)
+
         def body(i, state):
-            alive, seeds, gains = state
-            v = jnp.argmax(counter_of(alive)).astype(jnp.int32)
+            alive, counter, rows, seeds, gains = state
+            # argmax over vertices, first on ties (padding counts 0 and
+            # follows every vertex)
+            v = jnp.min(jnp.where(counter == counter.max(), vertex, n))
             covered = (R[:, v] > 0) & alive
             gain = covered.sum(dtype=jnp.int32)
-            return alive & ~covered, seeds.at[i].set(v), gains.at[i].set(gain)
+            alive = alive & ~covered
 
-        alive, seeds, gains = jax.lax.fori_loop(
+            def update(counter):
+                subtract = gain <= alive.sum(dtype=jnp.int32)
+                part, read = count(jnp.where(subtract, covered, alive))
+                return jnp.where(subtract, counter - part, part), read
+
+            counter, read = jax.lax.cond(
+                i < k - 1, update, lambda c: (c, jnp.int32(0)), counter)
+            return (alive, counter, rows + read,
+                    seeds.at[i].set(v), gains.at[i].set(gain))
+
+        alive, _, rows, seeds, gains = jax.lax.fori_loop(
             0, k, body,
-            (valid, jnp.zeros((k,), jnp.int32), jnp.zeros((k,), jnp.int32)),
+            (valid, counter, jnp.int32(theta),
+             jnp.zeros((k,), jnp.int32), jnp.zeros((k,), jnp.int32)),
         )
     elif method == "decrement":
+        def counter_of(rows):
+            return kops.coverage_matvec(rows.astype(jnp.float32), R)
+
         def body(i, state):
             alive, counter, seeds, gains = state
             v = jnp.argmax(counter).astype(jnp.int32)
@@ -96,12 +161,13 @@ def select_dense(R, valid, k: int, method: str = "rebuild"):
             (valid, counter_of(valid), jnp.zeros((k,), jnp.int32),
              jnp.zeros((k,), jnp.int32)),
         )
+        rows = jnp.int32((k + 1) * theta)
     else:
         raise ValueError(f"unknown method {method}")
 
     n_valid = jnp.maximum(valid.sum(dtype=jnp.float32), 1.0)
     covered_frac = gains.sum(dtype=jnp.float32) / n_valid
-    return seeds, covered_frac, gains
+    return DenseSelection(seeds, covered_frac, gains, rows)
 
 
 # --------------------------------------------------------------- sparse ----

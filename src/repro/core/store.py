@@ -185,6 +185,13 @@ def _coverage_stats(sizes, count: int, n: int) -> tuple[float, int]:
     return avg_cov, max(int(sizes.max()) if sizes.size else 1, 1)
 
 
+@jax.jit
+def _valid_rows(live, count):
+    """The filled rows still live, ``arange(capacity) < count`` and the
+    live bits, as one program (a select's host time pays per dispatch)."""
+    return (jnp.arange(live.shape[0]) < count) & live
+
+
 @partial(jax.jit, donate_argnums=(0,))
 def _write_rows(arena, rows, start):
     """In-place (donated) row-block write at dynamic offset ``start``."""
@@ -357,7 +364,7 @@ class _ArenaBase:
                 self.capacity * self.n / max(arena, 1))
 
     def _valid(self):
-        return (jnp.arange(self.capacity) < self.count) & self.live
+        return _valid_rows(self.live, self.count)
 
     def coverage_stats(self) -> tuple[float, int]:
         """(avg fractional set coverage, max set size) over *live* sets

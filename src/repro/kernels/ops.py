@@ -32,6 +32,8 @@ from repro import obs
 from repro.kernels import ref
 from repro.kernels.commit import arena_commit as _commit_pallas
 from repro.kernels.coverage_matvec import coverage_matvec as _coverage_pallas
+from repro.kernels.coverage_rows import coverage_rows as _rows_pallas
+from repro.kernels.coverage_rows import row_view as _row_view_pallas
 from repro.kernels.fused_select import fused_select as _select_pallas
 from repro.kernels.ic_frontier import ic_frontier_step as _frontier_pallas
 from repro.kernels.fm_interaction import fm_interaction as _fm_pallas
@@ -66,6 +68,18 @@ def coverage_matvec(alive, R, *, interpret=False, **kw):
     if _dispatch("coverage_matvec", interpret):
         return _coverage_pallas(alive, R, interpret=interpret, **kw)
     return ref.coverage_matvec_ref(alive, R)
+
+
+def row_view(R, valid, *, interpret=False):
+    if _dispatch("row_view", interpret):
+        return _row_view_pallas(R, valid, interpret=interpret)
+    return ref.row_view_ref(R, valid)
+
+
+def coverage_rows(listed, W, *, interpret=False):
+    if _dispatch("coverage_rows", interpret):
+        return _rows_pallas(listed, W, interpret=interpret)
+    return ref.coverage_rows_ref(listed, W)
 
 
 def fused_select(alive, R, *, interpret=False, **kw):

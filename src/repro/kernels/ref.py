@@ -19,6 +19,34 @@ def coverage_matvec_ref(alive, R):
     return alive.astype(jnp.float32) @ R.astype(jnp.float32)
 
 
+def row_view_ref(R, valid):
+    """R: (theta, n) uint8 0/1, valid: (theta,) -> (W (theta, C, 128)
+    int32, counts (32, C, 128) int32): the set-major bit-packed copy of
+    the arena (bit ``k`` of word ``(c, l)`` of a row is vertex
+    ``vertex_ids(C)[k, c, l]``; zero past ``n``) and the count of the
+    ``valid`` rows, vertex ``vertex_ids(C)[k, c, l]`` at ``[k, c, l]``."""
+    from repro.kernels.coverage_rows import vertex_ids, words_per_row
+    theta, n = R.shape
+    c = words_per_row(n)
+    ids = vertex_ids(c)
+    pad = c * 4096 - n
+    bits = jnp.pad(R, ((0, 0), (0, pad)))[:, ids].astype(jnp.uint32)
+    W = (bits << jnp.arange(32, dtype=jnp.uint32)[:, None, None]).sum(
+        axis=1, dtype=jnp.uint32)
+    counts = coverage_matvec_ref(valid, R).astype(jnp.int32)
+    return (jax.lax.bitcast_convert_type(W, jnp.int32),
+            jnp.pad(counts, (0, pad))[ids])
+
+
+def coverage_rows_ref(listed, W):
+    """listed: (theta,) bool, W: a `row_view_ref` arena -> (32, C, 128)
+    int32 count planes of the listed rows."""
+    words = jax.lax.bitcast_convert_type(W, jnp.uint32)[..., None]
+    bits = (words >> jnp.arange(32, dtype=jnp.uint32)) & 1
+    return jnp.einsum("r,rclk->kcl", listed.astype(jnp.int32),
+                      bits.astype(jnp.int32))
+
+
 def fused_select_ref(alive, R):
     """-> (max_count () f32, argmax () int32): one greedy round's reduction."""
     counter = coverage_matvec_ref(alive, R)

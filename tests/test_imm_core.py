@@ -159,6 +159,113 @@ def test_rebuild_equals_decrement():
     assert float(f1) == pytest.approx(float(f2))
 
 
+def _rule_rows(R, valid, k):
+    """Rows the adaptive update reads: every row once (the build), then
+    after each pick but the last the smaller of the rows it newly
+    covered (subtracted) and the rows alive after it (recounted), and
+    which of the two each update took."""
+    R = np.asarray(R).astype(bool)
+    alive = np.asarray(valid).copy()
+    rows, took = len(alive), []
+    for i in range(k):
+        v = int(np.argmax(R[alive].sum(axis=0)))
+        covered = alive & R[:, v]
+        alive &= ~covered
+        if i < k - 1:
+            c, a = int(covered.sum()), int(alive.sum())
+            rows += min(c, a)
+            took.append("subtract" if c <= a else "rebuild")
+    return rows, took
+
+
+def _arena(case):
+    """(R, valid, k, the update branches the case must take)."""
+    rng = np.random.default_rng(7)
+    if case == "subtract":        # few rows a pick: always subtract
+        R = rng.random((300, 90)) < 0.02
+        return R, np.ones(300, bool), 12, {"subtract"}
+    if case == "rebuild":         # vertex 3 holds 90% of the sets
+        R = rng.random((200, 50)) < 0.1
+        R[:, 3] = rng.random(200) < 0.9
+        return R, np.ones(200, bool), 6, {"subtract", "rebuild"}
+    if case == "masked":          # stale rows cleared, not a prefix
+        R = rng.random((160, 70)) < 0.15
+        return R, rng.random(160) < 0.6, 8, None
+    if case == "exhausted":       # k past the covering vertices
+        R = np.zeros((120, 40), bool)
+        R[:, :5] = rng.random((120, 5)) < 0.3
+        return R, np.ones(120, bool), 12, None
+    if case == "ragged":          # theta off the 128-set tile, > 255 rows
+        R = rng.random((700, 300)) < 0.4
+        return R, np.ones(700, bool), 10, None
+    raise ValueError(case)
+
+
+@pytest.fixture(params=["oracle", "pallas"])
+def kernels(request, monkeypatch):
+    """The dense selection's kernels: the jnp oracles (what a CPU runs)
+    or the Pallas kernels through the interpreter."""
+    if request.param == "pallas":
+        from functools import partial
+        from repro.kernels import coverage_rows as kr, ops
+        monkeypatch.setattr(ops, "row_view",
+                            partial(kr.row_view, interpret=True))
+        monkeypatch.setattr(ops, "coverage_rows",
+                            partial(kr.coverage_rows, interpret=True))
+        select_dense.clear_cache()
+        yield request.param
+        select_dense.clear_cache()
+    else:
+        yield request.param
+
+
+@pytest.mark.parametrize("case", ["subtract", "rebuild", "masked",
+                                  "exhausted", "ragged"])
+def test_select_dense_adaptive_update(case, kernels):
+    """C5's adaptive update picks what the plain greedy picks (seeds
+    included: first index on ties) and what the decremental baseline
+    picks, reading exactly the rows its rule names."""
+    R, valid, k, branches = _arena(case)
+    rows, took = _rule_rows(R, valid, k)
+    if branches is not None:
+        assert set(took) == branches
+    Rj, vj = jnp.asarray(R.astype(np.uint8)), jnp.asarray(valid)
+    got = select_dense(Rj, vj, k)
+    seeds, frac, gains = got
+    want_seeds, want_gains = _numpy_greedy(R, valid, k)
+    np.testing.assert_array_equal(np.asarray(seeds), want_seeds)
+    np.testing.assert_array_equal(np.asarray(gains), want_gains)
+    d_seeds, d_frac, d_gains = select_dense(Rj, vj, k, "decrement")
+    np.testing.assert_array_equal(np.asarray(seeds), np.asarray(d_seeds))
+    np.testing.assert_array_equal(np.asarray(gains), np.asarray(d_gains))
+    assert float(frac) == float(d_frac)
+    assert int(got.rows) == rows
+    if case == "exhausted":
+        assert not np.asarray(gains)[5:].any()
+
+
+def test_select_dense_rows_on_a_hand_built_arena(kernels):
+    """Eight valid sets over four vertices (a ninth, invalid, holds all
+    four).  The build reads all 9 sets.  Pick 1 (vertex 0) covers
+    3 and leaves 5: subtract 3.  Pick 2 (vertex 1, first of a tie with
+    vertex 3) covers 2 and leaves 3: subtract 2.  Pick 3 (vertex 3)
+    covers 2 and leaves 1: recount 1.  Pick 4 is the last: no update."""
+    R = np.zeros((9, 4), np.uint8)
+    for s, v in enumerate([0, 0, 0, 1, 1, 2, 3, 3]):
+        R[s, v] = 1
+    R[8] = 1
+    valid = np.arange(9) < 8
+    got = select_dense(jnp.asarray(R), jnp.asarray(valid), 4)
+    np.testing.assert_array_equal(np.asarray(got.seeds), [0, 1, 3, 2])
+    np.testing.assert_array_equal(np.asarray(got.gains), [3, 2, 2, 1])
+    assert int(got.rows) == 9 + 3 + 2 + 1
+    assert _rule_rows(R, valid, 4) == (15, ["subtract", "subtract",
+                                            "rebuild"])
+    # the baseline counts the whole arena: the build and every pick
+    assert int(select_dense(jnp.asarray(R), jnp.asarray(valid), 4,
+                            "decrement").rows) == 5 * 9
+
+
 def test_select_sparse_matches_dense():
     rng = np.random.default_rng(2)
     R = (rng.random((60, 32)) < 0.25).astype(np.uint8)

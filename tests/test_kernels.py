@@ -33,6 +33,51 @@ def test_coverage_matvec_tilings(tile_theta, tile_n):
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=1e-6)
 
 
+# ------------------------------------------------ row_view, coverage_rows ----
+
+def _per_vertex(planes, n):
+    """(4, C, 128) count planes -> (n,) counts in vertex order."""
+    from repro.kernels.coverage_rows import vertex_ids
+    v = np.asarray(vertex_ids(planes.shape[1])).ravel()
+    out = np.zeros(v.size, np.int64)
+    out[v] = np.asarray(planes).ravel()
+    assert not out[n:].any()          # vertices past n count 0
+    return out[:n]
+
+
+@pytest.mark.parametrize("theta,n", [(80, 300), (600, 5000), (256, 4096),
+                                     (300, 4097), (1, 33)])
+def test_row_view_sweep(theta, n):
+    rng = np.random.default_rng(theta * 7 + n)
+    R = (rng.random((theta, n)) < 0.3).astype(np.uint8)
+    valid = rng.random(theta) < 0.7
+    W, counts = ops.row_view(jnp.asarray(R), jnp.asarray(valid),
+                             interpret=True)
+    W_ref, counts_ref = ref.row_view_ref(jnp.asarray(R), jnp.asarray(valid))
+    np.testing.assert_array_equal(np.asarray(W), np.asarray(W_ref))
+    np.testing.assert_array_equal(np.asarray(counts), np.asarray(counts_ref))
+    np.testing.assert_array_equal(_per_vertex(counts, n),
+                                  R[valid].sum(axis=0))
+
+
+@pytest.mark.parametrize("theta,n", [(80, 300), (600, 5000), (1, 40)])
+@pytest.mark.parametrize("listed", [0, 1, 7, 255, 256, 511, 600])
+def test_coverage_rows_sweep(theta, n, listed):
+    """Listed prefixes around the packed sums' 255-row flush."""
+    listed = min(listed, theta)
+    rng = np.random.default_rng(listed + n)
+    R = (rng.random((theta, n)) < 0.5).astype(np.uint8)
+    W, _ = ref.row_view_ref(jnp.asarray(R), jnp.ones((theta,), bool))
+    rows = rng.permutation(theta)[:listed]
+    mask = np.zeros(theta, bool)
+    mask[rows] = True
+    got = ops.coverage_rows(jnp.asarray(mask), W, interpret=True)
+    want = ref.coverage_rows_ref(jnp.asarray(mask), W)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    np.testing.assert_array_equal(_per_vertex(got, n),
+                                  R[rows].sum(axis=0))
+
+
 # ---------------------------------------------------------- fused_select ----
 
 @pytest.mark.parametrize("theta,n", [(64, 100), (513, 300), (256, 2000)])

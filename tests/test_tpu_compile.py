@@ -5,7 +5,8 @@ Interpret mode runs a kernel's semantics on the CPU; it cannot see what
 the chip's compiler refuses (block shapes off the (8, 128) tiling, casts
 Mosaic does not lower, programs that do not fit HBM).  These compiles
 can: each kernel must lower to a ``tpu_custom_call``, and ``select_dense``
-over a 16,384 x 334,863 uint8 arena must fit the chip's 16 GiB.
+over a 16,384 x 334,863 uint8 arena must fit the chip's 16 GiB and, with
+the adaptive update, read the whole arena only outside its pick loop.
 
 The topology is described inside a module-scoped fixture, so a worker
 that cannot describe it skips these tests and every worker collects the
@@ -53,6 +54,8 @@ def _kernel_case(name, one_chip):
     import jax.numpy as jnp
     from repro.kernels.commit import arena_commit
     from repro.kernels.coverage_matvec import coverage_matvec
+    from repro.kernels.coverage_rows import (coverage_rows, row_view,
+                                             words_per_row)
     from repro.kernels.fused_select import fused_select
     from repro.kernels.ic_frontier import ic_frontier_step
     from repro.kernels.packed_count import packed_count, token_count
@@ -70,6 +73,11 @@ def _kernel_case(name, one_chip):
                              S((THETA, N), jnp.uint8)]),
         "fused_select": (fused_select, [S((THETA,), jnp.float32),
                                         S((THETA, N), jnp.uint8)]),
+        "row_view": (row_view, [S((THETA, N), jnp.uint8),
+                                S((THETA,), jnp.bool_)]),
+        "coverage_rows": (coverage_rows,
+                          [S((THETA,), jnp.bool_),
+                           S((THETA, words_per_row(N), 128), jnp.int32)]),
         "packed_count": (lambda p, a: packed_count(p, a, n=N),
                          [S((THETA, words), jnp.uint8),
                           S((THETA,), jnp.float32)]),
@@ -93,7 +101,7 @@ def _kernel_case(name, one_chip):
 
 @pytest.mark.parametrize("name", [
     "arena_commit_bitmap", "arena_commit_packed", "coverage_matvec",
-    "fused_select", "packed_count", "token_count_s64", "token_count_s512",
+    "fused_select", "row_view", "coverage_rows", "packed_count", "token_count_s64", "token_count_s512",
     "segment_or", "ic_frontier_n4096"])
 def test_kernel_compiles_for_v5e(one_chip, name):
     import jax
@@ -120,6 +128,50 @@ def test_select_dense_fits_v5e(one_chip, method, monkeypatch):
     total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
              + mem.temp_size_in_bytes)
     assert total < HBM_BYTES, f"select_dense needs {total / 2**30:.2f} GiB"
+
+
+def test_select_dense_pick_loop_reads_only_listed_rows(one_chip, monkeypatch):
+    """The adaptive update at com-Amazon's size: one kernel reads the
+    whole arena, before the pick loop (the row view, which also builds
+    the counter); inside the loop only the listed-rows kernel runs over
+    the view, and anything else that takes the arena reads one column
+    (at most theta elements).  No `coverage_matvec` pass is left, and
+    no other arena-sized copy is made."""
+    import re
+    import jax
+    import jax.numpy as jnp
+    from repro.core.selection import select_dense
+    from repro.kernels import ops
+    from repro.kernels.coverage_rows import words_per_row
+
+    monkeypatch.setattr(ops, "_on_tpu", lambda: True)
+    text = jax.jit(lambda R, v: select_dense(R, v, 50, "rebuild")).lower(
+        _spec(one_chip, (THETA, N), jnp.uint8),
+        _spec(one_chip, (THETA,), jnp.bool_)).compile().as_text()
+    calls = [re.search(r'op_name="([^"]*)"', line).group(1)
+             for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert sum("jit(row_view)" in c for c in calls) == 1
+    assert not any("coverage_matvec" in c for c in calls)
+    for c in calls:
+        assert ("jit(coverage_rows)" if "/while/" in c else
+                "jit(row_view)") in c, c
+    # every fused computation that takes the arena returns a column
+    arena = rf"u8\[{THETA},{N}\]"
+    readers = re.findall(rf"\n%(\S+) \([^\n]*?\w: {arena}[^\n]*\) -> "
+                         rf"(\w+)\[([\d,]*)\]", text)
+    assert readers
+    for name, _, dims in readers:
+        size = 1
+        for d in filter(None, dims.split(",")):
+            size *= int(d)
+        assert size <= THETA, name
+    # no instruction but the row view's kernel makes an arena-sized
+    # array: the others pass the arena or the view along
+    big = (rf"(?:{arena}|u8\[{N},{THETA}\]|"
+           rf"s32\[{THETA},{words_per_row(N)},128\])")
+    made = set(re.findall(rf"= {big}\S* (\S+)\(", text))
+    assert made <= {"parameter", "get-tuple-element", "bitcast"}, made
 
 
 @pytest.mark.parametrize("stable", [False, True])
