@@ -8,8 +8,8 @@ from repro.graphs import rmat_graph
 # a power-law social graph (synthetic stand-in for a SNAP graph)
 graph = rmat_graph(n=2_000, m=16_000, seed=0)
 
-# EfficientIMM defaults: fused counting (C3), RRRset-partitioned rebuild
-# selection (C1+C5), adaptive representation (C4)
+# EfficientIMM defaults: fused counting (C3), selection with the adaptive
+# counter update (C5), adaptive representation (C4)
 result = imm(graph, IMMConfig(k=10, eps=0.5, model="IC", max_theta=4096))
 
 print(f"graph: n={graph.n} m={graph.m}")

@@ -35,9 +35,11 @@ XLA_FLAGS="--xla_force_host_platform_device_count=4${XLA_FLAGS:+ $XLA_FLAGS}" \
 
 # the 2D acceptance cell on a forced-8-device 2x4 mesh: theta over 2
 # shards x vertices over 4 — per-device arena buffers are (cap_local,
-# n/4), the full (theta, n) arena never exists on one device, and
+# n/4), the full (theta, n) arena never exists on one device,
 # select/influence answers are bitwise identical to the single-device
-# engine (tests/force_mesh_check.py asserts all of it); the packed and
+# engine, and both selection rules over the tiles and over tile-local
+# index lists give the plain greedy's seeds, gains and covered_frac
+# (tests/force_mesh_check.py asserts all of it); the packed and
 # compressed cells re-prove it with IMPack-encoded tiles, whose
 # per-device buffers are (cap_local, w_local) at the codec width
 PYTHONPATH="src${PYTHONPATH:+:$PYTHONPATH}" \

@@ -45,7 +45,6 @@ from repro.core.sampler import (
     default_sampler_name,
 )
 from repro.core.selection import (
-    greedy_select,
     select_dense,
     select_sparse,
     select_dense_sharded,
@@ -73,7 +72,7 @@ __all__ = [
     "register_backend", "get_backend", "registered_backends",
     "register_sampler", "get_sampler", "registered_samplers",
     "default_sampler_name",
-    "greedy_select", "select_dense", "select_sparse", "select_dense_sharded",
+    "select_dense", "select_sparse", "select_dense_sharded",
     "register_selection", "get_selection",
     "choose_representation", "bitmap_to_indices", "indices_to_bitmap",
     "l_pad_for",

@@ -19,7 +19,8 @@ Pieces:
     (the legacy monolithic spellings still resolve, deprecated);
   * selection goes through the `SelectionStrategy` registry
     (``repro.core.selection.get_selection``: rebuild/decrement x
-    dense/sparse/sharded) instead of if/elif dispatch;
+    dense/sparse/packed/compressed/sharded/sharded-sparse, every one the
+    same greedy loop) instead of if/elif dispatch;
   * sampled sets land in a preallocated `RRRStore` arena (amortized
     doubling, in-place batch writes — see ``repro.core.store``), so
     ``extend``/``select`` never re-concatenate O(theta) rows; with a mesh
@@ -82,14 +83,16 @@ class IMMConfig:
     batch: int = 256                  # RRR sets per sampling call
     max_theta: int = 1 << 16          # safety cap (config-controlled)
     dense_sampler_max_n: int = 4096   # use the MXU log-semiring sampler below
-    selection_method: str = "rebuild"    # "rebuild" (C5) | "decrement"
+    # the greedy loop's counter update: "rebuild" (C5's adaptive update,
+    # from the smaller of the newly covered and the surviving sets) or
+    # "decrement" (the Ripples baseline: subtract the covered sets)
+    selection_method: str = "rebuild"
     adaptive_representation: bool = True  # C4
     # below this n the dense bitmap wins regardless of coverage (the
     # mat-vec is MXU/cache-friendly and the bitmap->indices conversion
     # costs more than it saves — measured: LT replicas at n~4k ran 10x
     # slower through the index path; EXPERIMENTS §Paper-tables)
     sparse_rep_min_n: int = 65536
-    fuse_counters: bool = True            # C3 (informational; sampler always fuses)
     switch_ratio: int = 32
     # "auto" resolves to "sharded" when the engine has a mesh, "bitmap"
     # otherwise; "sharded" demands a mesh.  "packed" (bit-packed, 8x

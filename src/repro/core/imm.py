@@ -26,7 +26,7 @@ Callers that issue more than one query per sampled store should hold an
 
 The paper-faithful baseline and the optimized path both remain first-class:
 
-    IMMConfig(selection_method="decrement", fuse_counters=False,
+    IMMConfig(selection_method="decrement",
               adaptive_representation=False)   # Ripples-style baseline
     IMMConfig()                                # EfficientIMM defaults
 """

@@ -1,42 +1,53 @@
 """Find_Most_Influential_Set (paper Alg. 2) — greedy max-coverage.
 
-Two strategies, both over bitmap ``R (theta, n) uint8`` or index-list
-``R_idx (theta, L) int32`` representations:
+Every selection runs one pick loop, `_greedy`: count the sets that hold
+each vertex once, then ``k`` times pick the vertex with the largest
+count (first on ties), mark the alive sets that hold it covered, and
+update the counter by one of two rules:
 
-  * ``method="rebuild"``   — EfficientIMM (paper C5 "adaptive counter
-    update"): after one counter build, each pick updates the counter
-    from whichever is the smaller set of rows — subtract the sets it
-    newly covered, or rebuild from the sets that survive it — so no row
-    is read for nothing.  The dense version (`select_dense`) reads only
-    those rows (Pallas kernels: kernels/coverage_rows.py); the sparse,
-    sharded and fused ones rebuild from every surviving set each pick
-    (``counter = alive @ R``, kernels/coverage_matvec.py /
-    fused_select.py).
-  * ``method="decrement"`` — Ripples-faithful baseline: keep a running
-    counter and subtract the contribution of the sets covered by the newly
-    selected seed, counted over the whole arena.
+  * ``method="rebuild"`` — EfficientIMM (paper C5 "adaptive counter
+    update"): after each pick but the last, count whichever is the
+    smaller set of rows — the sets the pick newly covered (subtracted)
+    or the sets alive after it (counted afresh).  Where a count reads
+    only the rows it is given (`select_dense`'s row view, Pallas
+    kernels in kernels/coverage_rows.py) no row is read for nothing;
+    where a count is a full pass, either branch costs one pass.
+  * ``method="decrement"`` — Ripples-faithful baseline: subtract the
+    count of the newly covered sets after every pick, counted over the
+    whole arena.
 
-The two are algebraically identical (property-tested); their cost profiles
-differ exactly as the paper describes — with skewed graphs most sets contain
-the first seeds, so the decremental update touches far more rows.
+Counts are exact integers, so the two rules pick the same seeds with the
+same gains (property-tested); their cost profiles differ exactly as the
+paper describes — with skewed graphs most sets contain the first seeds,
+so the decremental update touches far more rows.
 
-``select_dense_sharded`` is the multi-device version (paper C1 RRRset
-partitioning, end-to-end since the `ShardedStore` rework): the theta axis
-of ``R`` is sharded across the mesh and each device reduces over *its own
-resident arena shard* — when fed a ``ShardedStore`` view the input specs
-match the store's native ``P(theta_axes, None)`` layout, so no arena data
-moves on entry.  Per greedy round only reduced quantities cross devices
-(the ``(n,)`` counter psum standing in for the paper's atomic adds, and a
-scalar gain); arena rows never do.  Both counter-update methods exist as
-true implementations here: ``rebuild`` re-reduces the surviving local rows
-every round (C5), ``decrement`` keeps a *local partial counter* per shard
-and subtracts the covered local rows' contribution — the running-counter
-baseline, executed shard-locally.
+Each at-rest form gives the loop its count and its pick:
 
-The `SelectionStrategy` registry at the bottom exposes all of these to the
-`InfluenceEngine` as ``(method, layout)`` pairs — rebuild/decrement x
-dense/sparse/sharded — so new strategies plug in via ``register_selection``
-instead of growing an if/elif ladder in the driver.
+  * bitmap rows on one chip (`select_dense`): the row view and the
+    listed-rows kernel for ``rebuild``, `coverage_matvec` for
+    ``decrement``;
+  * bitmap, bit-packed or token rows (`_codec_tally`): counted by
+    `repro.kernels.ops.arena_count`, never widened or decoded whole, the
+    winner's membership read as one column — `select_packed` and
+    `select_compressed` (`repro.core.pack.selection`) on one chip,
+    `select_dense_sharded` per tile;
+  * index lists (`_index_tally`) through `bincount_weighted` —
+    `select_sparse` on one chip, `select_sparse_sharded` per tile.
+
+On a mesh (paper C1 RRRset partitioning) the theta axis of the arena is
+sharded and each device counts *its own resident tile* — a
+`ShardedStore` view enters with the store's native layout, so no arena
+data moves on entry.  The counter is the psum of the tiles' counts over
+the theta axes (the paper's atomic global counter, vertex-sharded on 2D
+meshes), and per pick only reduced quantities cross devices: the
+counter, the per-vertex-shard argmax candidates and scalar gains; arena
+rows never do.
+
+The `SelectionStrategy` registry at the bottom exposes these to the
+`InfluenceEngine` as ``<method>-<layout>`` names — rebuild/decrement x
+dense/sparse/packed/compressed/sharded/sharded-sparse — so new
+strategies plug in via ``register_selection`` instead of growing an
+if/elif ladder in the driver.
 
 Every strategy treats ``valid`` as an *arbitrary* row mask, not a prefix:
 ``alive`` starts from it, the counter reduction masks by it, and
@@ -59,6 +70,120 @@ from repro.graphs.partition import vertex_partition
 from repro.kernels import ops as kops
 from repro.kernels.coverage_rows import vertex_ids
 from repro.sparse.scatter import bincount_weighted
+
+
+# ------------------------------------------------------------ the loop ----
+
+def _one_chip(x):
+    return x
+
+
+def _greedy(k: int, valid, *, count, pick, rule: str, build=None,
+            total=_one_chip):
+    """The greedy pick loop every selection runs.
+
+    ``count(mask) -> (counter, rows)``: the per-vertex counts of the
+    ``mask`` rows and the arena rows counting them read.  ``build`` is
+    the first count, of ``valid``; it defaults to ``count(valid)``.
+    ``pick(counter, alive) -> (v, covered)``: the vertex with the
+    largest count, first on ties, and the alive rows that hold it.
+    ``total(x)`` sums a per-device scalar over the mesh's theta axes
+    (the identity on one chip).
+
+    ``rule="rebuild"`` updates the counter after each pick but the last
+    from the smaller of the newly covered rows (subtracted) and the rows
+    alive after the pick (counted afresh); ``rule="decrement"``
+    subtracts the count of the covered rows after every pick.
+
+    Returns (seeds (k,) int32, covered_frac () f32, gains (k,) int32,
+    rows () int32).
+    """
+    if rule not in ("rebuild", "decrement"):
+        raise ValueError(f"unknown method {rule}")
+    counter, rows = count(valid) if build is None else build
+
+    def body(i, state):
+        alive, counter, rows, seeds, gains = state
+        v, covered = pick(counter, alive)
+        gain = total(covered.sum(dtype=jnp.int32))
+        alive = alive & ~covered
+        if rule == "rebuild":
+            def update(counter):
+                subtract = gain <= total(alive.sum(dtype=jnp.int32))
+                part, read = count(jnp.where(subtract, covered, alive))
+                return jnp.where(subtract, counter - part, part), read
+
+            counter, read = jax.lax.cond(
+                i < k - 1, update, lambda c: (c, jnp.int32(0)), counter)
+        else:
+            part, read = count(covered)
+            counter = counter - part
+        return (alive, counter, rows + read,
+                seeds.at[i].set(v), gains.at[i].set(gain))
+
+    _, _, rows, seeds, gains = jax.lax.fori_loop(
+        0, k, body,
+        (valid, counter, rows, jnp.zeros((k,), jnp.int32),
+         jnp.zeros((k,), jnp.int32)),
+    )
+    n_valid = jnp.maximum(total(valid.sum(dtype=jnp.float32)), 1.0)
+    covered_frac = gains.sum(dtype=jnp.float32) / n_valid
+    return seeds, covered_frac, gains, rows
+
+
+def _codec_tally(R, codec=None, interpret: bool = False):
+    """``(tally, member)`` of an arena, or one tile of it, at rest in the
+    form ``codec`` names (bitmap rows when None): ``tally(mask)`` is the
+    ``(n_cols,) f32`` per-column count of the ``mask`` rows through
+    `repro.kernels.ops.arena_count`, ``member(v)`` the ``(rows,) bool``
+    membership of column ``v``, a one-column read."""
+    def tally(mask):
+        return kops.arena_count(R, mask, codec=codec, interpret=interpret)
+
+    def member(v):
+        if codec is None or codec.kind == "bitmap":
+            return R[:, v] > 0
+        return codec.decode_cols(R, v.reshape(1))[:, 0]
+
+    return tally, member
+
+
+def _index_tally(R_idx, n: int):
+    """``(tally, member)`` of index lists ``(rows, L) int32`` with
+    sentinel ``n`` padding: counts through `bincount_weighted`,
+    membership as a list scan."""
+    def tally(mask):
+        return bincount_weighted(R_idx, mask.astype(jnp.float32)[:, None], n)
+
+    def member(v):
+        return (R_idx == v).any(axis=1)
+
+    return tally, member
+
+
+def _full_pass(k, valid, rule, tally, member, *, axes=None,
+               vertex_axis=None, n=None, starts=None):
+    """`_greedy` whose every count is one ``tally`` pass over the arena,
+    or over one tile of it inside shard_map (``axes`` the theta axes;
+    ``vertex_axis``, ``n`` and ``starts`` as `_vertex_sharded_pick`
+    takes them).  The counter is kept global: the tiles' counts psummed
+    over ``axes``."""
+    total = _one_chip if axes is None else partial(jax.lax.psum,
+                                                   axis_name=axes)
+    rows = jnp.int32(valid.shape[0])
+
+    def count(mask):
+        return total(tally(mask)), rows
+
+    def pick(counter, alive):
+        if vertex_axis is not None:
+            return _vertex_sharded_pick(
+                counter, alive, n, vertex_axis, member, starts)
+        v = jnp.argmax(counter).astype(jnp.int32)
+        return v, member(v) & alive
+
+    return _greedy(k, valid, count=count, pick=pick, rule=rule,
+                   total=total)
 
 
 # ---------------------------------------------------------------- dense ----
@@ -112,62 +237,24 @@ def select_dense(R, valid, k: int, method: str = "rebuild"):
     gains (k,) int32), and ``rows`` () int32.
     """
     theta, n = R.shape
-    if method == "rebuild":
-        W, counter = kops.row_view(R, valid)
-        vertex = vertex_ids(W.shape[1])
+    if method != "rebuild":
+        return DenseSelection(*_full_pass(k, valid, method,
+                                          *_codec_tally(R)))
+    W, counter = kops.row_view(R, valid)
+    vertex = vertex_ids(W.shape[1])
 
-        def count(mask):
-            return kops.coverage_rows(mask, W), mask.sum(dtype=jnp.int32)
+    def count(mask):
+        return kops.coverage_rows(mask, W), mask.sum(dtype=jnp.int32)
 
-        def body(i, state):
-            alive, counter, rows, seeds, gains = state
-            # argmax over vertices, first on ties (padding counts 0 and
-            # follows every vertex)
-            v = jnp.min(jnp.where(counter == counter.max(), vertex, n))
-            covered = (R[:, v] > 0) & alive
-            gain = covered.sum(dtype=jnp.int32)
-            alive = alive & ~covered
+    def pick(counter, alive):
+        # argmax over vertices, first on ties (padding counts 0 and
+        # follows every vertex)
+        v = jnp.min(jnp.where(counter == counter.max(), vertex, n))
+        return v, (R[:, v] > 0) & alive
 
-            def update(counter):
-                subtract = gain <= alive.sum(dtype=jnp.int32)
-                part, read = count(jnp.where(subtract, covered, alive))
-                return jnp.where(subtract, counter - part, part), read
-
-            counter, read = jax.lax.cond(
-                i < k - 1, update, lambda c: (c, jnp.int32(0)), counter)
-            return (alive, counter, rows + read,
-                    seeds.at[i].set(v), gains.at[i].set(gain))
-
-        alive, _, rows, seeds, gains = jax.lax.fori_loop(
-            0, k, body,
-            (valid, counter, jnp.int32(theta),
-             jnp.zeros((k,), jnp.int32), jnp.zeros((k,), jnp.int32)),
-        )
-    elif method == "decrement":
-        def counter_of(rows):
-            return kops.coverage_matvec(rows.astype(jnp.float32), R)
-
-        def body(i, state):
-            alive, counter, seeds, gains = state
-            v = jnp.argmax(counter).astype(jnp.int32)
-            covered = (R[:, v] > 0) & alive
-            gain = covered.sum(dtype=jnp.int32)
-            counter = counter - counter_of(covered)
-            return (alive & ~covered, counter,
-                    seeds.at[i].set(v), gains.at[i].set(gain))
-
-        alive, _, seeds, gains = jax.lax.fori_loop(
-            0, k, body,
-            (valid, counter_of(valid), jnp.zeros((k,), jnp.int32),
-             jnp.zeros((k,), jnp.int32)),
-        )
-        rows = jnp.int32((k + 1) * theta)
-    else:
-        raise ValueError(f"unknown method {method}")
-
-    n_valid = jnp.maximum(valid.sum(dtype=jnp.float32), 1.0)
-    covered_frac = gains.sum(dtype=jnp.float32) / n_valid
-    return DenseSelection(seeds, covered_frac, gains, rows)
+    return DenseSelection(*_greedy(k, valid, count=count, pick=pick,
+                                   rule=method,
+                                   build=(counter, jnp.int32(theta))))
 
 
 # --------------------------------------------------------------- sparse ----
@@ -177,50 +264,7 @@ def select_sparse(R_idx, valid, n: int, k: int, method: str = "rebuild"):
     """R_idx: (theta, L) int32 with sentinel ``n`` padding; valid:
     (theta,) bool.  Single-device.  Returns (seeds (k,) int32,
     covered_frac () f32, gains (k,) int32)."""
-    theta, L = R_idx.shape
-
-    def counter_of(alive):
-        return bincount_weighted(R_idx, alive.astype(jnp.float32)[:, None], n)
-
-    def contains(v):
-        return (R_idx == v).any(axis=1)
-
-    if method == "rebuild":
-        def body(i, state):
-            alive, seeds, gains = state
-            counter = counter_of(alive)
-            v = jnp.argmax(counter).astype(jnp.int32)
-            covered = contains(v) & alive
-            gain = covered.sum(dtype=jnp.int32)
-            return alive & ~covered, seeds.at[i].set(v), gains.at[i].set(gain)
-
-        alive, seeds, gains = jax.lax.fori_loop(
-            0, k, body,
-            (valid, jnp.zeros((k,), jnp.int32), jnp.zeros((k,), jnp.int32)),
-        )
-    elif method == "decrement":
-        counter0 = counter_of(valid)
-
-        def body(i, state):
-            alive, counter, seeds, gains = state
-            v = jnp.argmax(counter).astype(jnp.int32)
-            covered = contains(v) & alive
-            gain = covered.sum(dtype=jnp.int32)
-            counter = counter - bincount_weighted(
-                R_idx, covered.astype(jnp.float32)[:, None], n)
-            return (alive & ~covered, counter,
-                    seeds.at[i].set(v), gains.at[i].set(gain))
-
-        alive, _, seeds, gains = jax.lax.fori_loop(
-            0, k, body,
-            (valid, counter0, jnp.zeros((k,), jnp.int32),
-             jnp.zeros((k,), jnp.int32)),
-        )
-    else:
-        raise ValueError(f"unknown method {method}")
-
-    n_valid = jnp.maximum(valid.sum(dtype=jnp.float32), 1.0)
-    return seeds, gains.sum(dtype=jnp.float32) / n_valid, gains
+    return _full_pass(k, valid, method, *_index_tally(R_idx, n))[:3]
 
 
 # -------------------------------------------------------------- sharded ----
@@ -286,6 +330,27 @@ def _starts_for(mesh, vertex_axis, n, partition):
     return jnp.asarray(partition.starts, jnp.int32)
 
 
+def _on_mesh(mesh, R, valid, k, method, provider, *, theta_axes,
+             vertex_axis, n, starts):
+    """`_full_pass` over every ``(theta, vertex)`` tile of ``R``, the
+    tile's ``(tally, member)`` from ``provider(tile)``; ``starts``, when
+    not None, enters replicated.  Returns the replicated (seeds,
+    covered_frac, gains)."""
+    axes = tuple(theta_axes)
+
+    def local_select(R_local, valid_local, starts=None):
+        return _full_pass(k, valid_local, method, *provider(R_local),
+                          axes=axes, vertex_axis=vertex_axis, n=n,
+                          starts=starts)[:3]
+
+    args, specs = (R, valid), (P(axes, vertex_axis), P(axes))
+    if starts is not None:
+        args, specs = args + (starts,), specs + (P(),)
+    fn = shard_map(local_select, mesh=mesh, in_specs=specs,
+                   out_specs=(P(), P(), P()))
+    return fn(*args)
+
+
 def select_dense_sharded(mesh, R, valid, k: int, *,
                          theta_axes=("data",), vertex_axis=None,
                          method: str = "rebuild", n: int | None = None,
@@ -294,13 +359,14 @@ def select_dense_sharded(mesh, R, valid, k: int, *,
     """EfficientIMM selection with the theta axis sharded over ``theta_axes``
     (paper C1) and, optionally, the vertex axis over ``vertex_axis``.
 
-    ``R (theta, n_pad) uint8`` and ``valid (theta,) bool`` enter with
-    specs ``P(theta_axes, vertex_axis)`` / ``P(theta_axes)`` — a
+    ``R (theta, n_pad)`` and ``valid (theta,) bool`` enter with specs
+    ``P(theta_axes, vertex_axis)`` / ``P(theta_axes)`` — a
     `ShardedStore` view already carries exactly this layout (1D stores
     with ``vertex_axis=None``, 2D stores with the vertex axis resident),
     so its arena tiles are consumed in place; replicated arrays are
-    scattered on entry.  ``valid`` may be any mask, not just a prefix —
-    sharded stores fill each shard independently.  ``n`` is the real
+    scattered on entry.  ``R`` holds bitmap rows, or the tiles of the
+    form ``codec`` names.  ``valid`` may be any mask, not just a prefix
+    — sharded stores fill each shard independently.  ``n`` is the real
     vertex count: on 2D layouts the column dimension is padded to
     ``Dv * n_local`` and the pad columns must never win the argmax
     (they are all-zero, but an all-zero round would otherwise pick one).
@@ -309,108 +375,33 @@ def select_dense_sharded(mesh, R, valid, k: int, *,
     it as ``store.partition``); when None the canonical equal-block
     layout for ``n`` is assumed.
 
-    Inside shard_map each device owns a ``(theta_local, n_local)`` tile.
-    Per greedy round only reduced quantities cross devices: the counter
-    ``psum`` over the theta axis (the paper's atomic global counter,
-    staying vertex-sharded), the per-vertex-shard argmax candidates
-    (``all_gather`` of ``Dv`` scalars), the covered-rows bits psum-or over
-    the vertex axis, and the scalar gain — never arena rows or columns.
-    The greedy argmax is computed redundantly on every device (cheap,
-    avoids a broadcast).
-
-    Every per-tile reduction runs through the `repro.kernels.ops`
-    dispatch: bitmap tiles reduce with `coverage_matvec`, packed and
-    compressed tiles with the decode-and-count kernels (``interpret=True``
-    runs them through the Pallas interpreter), so no tile is ever widened
-    or whole-tile decoded; membership of the winner is a one-column read
-    (``decode_cols`` on encoded tiles).
-
-    ``method="rebuild"`` re-reduces the surviving local rows every round
-    (C5).  ``method="decrement"`` is the true decremental update executed
-    tile-locally: each device keeps a partial counter over its own rows
-    and columns and subtracts the contribution of its newly-covered rows,
-    so the running global counter is ``psum`` of partials.  Both are
-    exact over integer-valued f32 counts and return identical selections.
+    Inside shard_map each device owns a ``(theta_local, n_local)`` tile
+    and runs `_greedy` over it: its counts go through
+    `repro.kernels.ops.arena_count` (``interpret=True`` runs the Pallas
+    kernels through the interpreter), so no tile is ever widened or
+    whole-tile decoded, and are psummed over the theta axes into the
+    global counter; the argmax resolves over the vertex axis from ``Dv``
+    all-gathered candidates, the winner's membership is a one-column
+    read psum-or'ed over the vertex axis, and gains are psummed — never
+    arena rows or columns.  The pick is computed redundantly on every
+    device (cheap, avoids a broadcast).  Both rules are exact over
+    integer-valued f32 counts and return identical selections.
 
     Returns replicated ``(seeds (k,) int32, covered_frac () f32,
     gains (k,) int32)``.
     """
-    axes = tuple(theta_axes)
-    if method not in ("rebuild", "decrement"):
-        raise ValueError(f"unknown method {method}")
-    starts_arr = _starts_for(mesh, vertex_axis, n, partition)
-    kind = "bitmap" if codec is None else codec.kind
-
-    def local_select(R_local, valid_local, starts=None):
-        def partial_of(alive):
-            return kops.arena_count(R_local, alive, codec=codec,
-                                    interpret=interpret)
-
-        def member_local(lv):
-            if kind == "bitmap":
-                return R_local[:, lv] > 0
-            return codec.decode_cols(R_local, lv.reshape(1))[:, 0]
-
-        def pick(counter, alive):
-            if vertex_axis is not None:
-                return _vertex_sharded_pick(
-                    counter, alive, n, vertex_axis, member_local, starts)
-            v = jnp.argmax(counter).astype(jnp.int32)
-            return v, member_local(v) & alive
-
-        if method == "rebuild":
-            def body(i, state):
-                alive, seeds, gains = state
-                counter = jax.lax.psum(partial_of(alive), axes)
-                v, covered = pick(counter, alive)
-                gain = jax.lax.psum(covered.sum(dtype=jnp.int32), axes)
-                return (alive & ~covered,
-                        seeds.at[i].set(v), gains.at[i].set(gain))
-
-            alive, seeds, gains = jax.lax.fori_loop(
-                0, k, body,
-                (valid_local, jnp.zeros((k,), jnp.int32),
-                 jnp.zeros((k,), jnp.int32)),
-            )
-        else:
-            def body(i, state):
-                alive, partial, seeds, gains = state
-                counter = jax.lax.psum(partial, axes)
-                v, covered = pick(counter, alive)
-                gain = jax.lax.psum(covered.sum(dtype=jnp.int32), axes)
-                partial = partial - partial_of(covered)
-                return (alive & ~covered, partial,
-                        seeds.at[i].set(v), gains.at[i].set(gain))
-
-            alive, _, seeds, gains = jax.lax.fori_loop(
-                0, k, body,
-                (valid_local, partial_of(valid_local),
-                 jnp.zeros((k,), jnp.int32), jnp.zeros((k,), jnp.int32)),
-            )
-        n_valid = jnp.maximum(
-            jax.lax.psum(valid_local.sum(dtype=jnp.float32), axes), 1.0)
-        return seeds, gains.sum(dtype=jnp.float32) / n_valid, gains
-
-    out_specs = (P(), P(), P())
-    if starts_arr is None:
-        fn = shard_map(
-            local_select, mesh=mesh,
-            in_specs=(P(axes, vertex_axis), P(axes)), out_specs=out_specs,
-        )
-        return fn(R, valid)
-    fn = shard_map(
-        local_select, mesh=mesh,
-        in_specs=(P(axes, vertex_axis), P(axes), P()), out_specs=out_specs,
-    )
-    return fn(R, valid, starts_arr)
+    return _on_mesh(
+        mesh, R, valid, k, method,
+        lambda tile: _codec_tally(tile, codec, interpret),
+        theta_axes=theta_axes, vertex_axis=vertex_axis, n=n,
+        starts=_starts_for(mesh, vertex_axis, n, partition))
 
 
 def select_sparse_sharded(mesh, R_idx, valid, n: int, k: int, *,
                           theta_axes=("data",), vertex_axis=None,
                           method: str = "rebuild", partition=None):
     """Greedy max-coverage over *sharded index lists* — the C4 sparse
-    representation on a 1D or 2D mesh, lifting the old bitmap-only
-    restriction of the sharded pipeline.
+    representation on a 1D or 2D mesh.
 
     ``R_idx (Dt * cap_local, Dv * l_pad) int32`` enters with spec
     ``P(theta_axes, vertex_axis)``: tile ``(t, v)`` holds, for each of
@@ -420,170 +411,26 @@ def select_sparse_sharded(mesh, R_idx, valid, n: int, k: int, *,
     width to its own columns).  ``valid (Dt * cap_local,) bool`` is
     ``P(theta_axes)``.
 
-    Per greedy round each tile bincounts its own lists into an
-    ``(n_local,)`` partial; the psum over the theta axis keeps the
-    counter vertex-sharded, the argmax crosses the vertex axis as ``Dv``
-    (value, index) scalars, and membership of the winner is a tile-local
-    list scan psum-or'ed over the vertex axis — reduced quantities only,
-    as in the dense strategy.  Selections are identical to the dense
-    strategies over the same rows (exact integer counts).
+    Each tile bincounts its own lists into an ``(n_local,)`` count, and
+    the rest is `select_dense_sharded`'s loop: the psum over the theta
+    axes keeps the counter vertex-sharded, the argmax crosses the vertex
+    axis as ``Dv`` (value, index) scalars, and membership of the winner
+    is a tile-local list scan psum-or'ed over the vertex axis.
+    Selections are identical to the dense strategies over the same rows
+    (exact integer counts).
 
     Returns replicated ``(seeds (k,) int32, covered_frac () f32,
     gains (k,) int32)``.
     """
-    axes = tuple(theta_axes)
-    if method not in ("rebuild", "decrement"):
-        raise ValueError(f"unknown method {method}")
     Dv = int(mesh.shape[vertex_axis]) if vertex_axis else 1
     # the vertex-block layout — must match the tiles
     # ShardedStore.index_view emitted, or local ids mean the wrong vertex
     part = partition if partition is not None else vertex_partition(n, Dv)
-    n_local = part.block
-    starts_arr = _starts_for(mesh, vertex_axis, n, part)
-
-    def local_select(R_local, valid_local, starts=None):
-        def counter_of(alive):
-            partial = bincount_weighted(
-                R_local, alive.astype(jnp.float32)[:, None], n_local)
-            return jax.lax.psum(partial, axes)
-
-        def pick(counter, alive):
-            if vertex_axis is not None:
-                return _vertex_sharded_pick(
-                    counter, alive, n, vertex_axis,
-                    lambda lv: (R_local == lv).any(axis=1), starts)
-            v = jnp.argmax(counter).astype(jnp.int32)
-            return v, ((R_local == v).any(axis=1)) & alive
-
-        def dec_of(covered):
-            return bincount_weighted(
-                R_local, covered.astype(jnp.float32)[:, None], n_local)
-
-        if method == "rebuild":
-            def body(i, state):
-                alive, seeds, gains = state
-                v, covered = pick(counter_of(alive), alive)
-                gain = jax.lax.psum(covered.sum(dtype=jnp.int32), axes)
-                return (alive & ~covered,
-                        seeds.at[i].set(v), gains.at[i].set(gain))
-
-            alive, seeds, gains = jax.lax.fori_loop(
-                0, k, body,
-                (valid_local, jnp.zeros((k,), jnp.int32),
-                 jnp.zeros((k,), jnp.int32)),
-            )
-        else:
-            partial0 = bincount_weighted(
-                R_local, valid_local.astype(jnp.float32)[:, None], n_local)
-
-            def body(i, state):
-                alive, partial, seeds, gains = state
-                v, covered = pick(jax.lax.psum(partial, axes), alive)
-                gain = jax.lax.psum(covered.sum(dtype=jnp.int32), axes)
-                partial = partial - dec_of(covered)
-                return (alive & ~covered, partial,
-                        seeds.at[i].set(v), gains.at[i].set(gain))
-
-            alive, _, seeds, gains = jax.lax.fori_loop(
-                0, k, body,
-                (valid_local, partial0, jnp.zeros((k,), jnp.int32),
-                 jnp.zeros((k,), jnp.int32)),
-            )
-        n_valid = jnp.maximum(
-            jax.lax.psum(valid_local.sum(dtype=jnp.float32), axes), 1.0)
-        return seeds, gains.sum(dtype=jnp.float32) / n_valid, gains
-
-    if starts_arr is None:
-        fn = shard_map(
-            local_select, mesh=mesh,
-            in_specs=(P(axes, vertex_axis), P(axes)),
-            out_specs=(P(), P(), P()),
-        )
-        return fn(R_idx, valid)
-    fn = shard_map(
-        local_select, mesh=mesh,
-        in_specs=(P(axes, vertex_axis), P(axes), P()),
-        out_specs=(P(), P(), P()),
-    )
-    return fn(R_idx, valid, starts_arr)
-
-
-# ---------------------------------------------------------------- fused ----
-
-@partial(jax.jit, static_argnames=("n", "k", "method", "codec", "interpret"))
-def select_fused(R, valid, n: int, k: int, method: str = "rebuild", *,
-                 codec=None, interpret: bool = False):
-    """Greedy selection whose per-round reduction runs through the
-    `repro.kernels.ops` dispatch (Pallas on TPU, ``interpret=True`` for
-    CPU kernel validation, jnp oracle elsewhere) — the fused counterpart
-    of `select_dense`/`select_packed`/`select_compressed`, bitwise-equal
-    to all of them over the same rows (exact integer counts in f32, and
-    the `fused_select` kernel's tie-break equals ``jnp.argmax``).
-
-    ``R`` is the at-rest arena in the layout ``codec`` names: raw
-    ``(theta, n) uint8`` bitmaps when ``codec`` is None/bitmap, encoded
-    ``(theta, codec.width)`` tiles otherwise — encoded arenas are
-    counted with the decode-and-count kernels, so the decoded
-    ``(theta, n)`` block never exists.  For bitmap rebuild rounds the
-    `fused_select` kernel returns the winning vertex directly and the
-    per-round ``(n,)`` counter is never materialized either.
-    """
-    kind = "bitmap" if codec is None else codec.kind
-
-    def counter_of(alive):
-        return kops.arena_count(R, alive, codec=codec, interpret=interpret)
-
-    def member(v):
-        if kind == "bitmap":
-            return R[:, v] > 0
-        return codec.decode_cols(R, v.reshape(1))[:, 0]
-
-    if method == "rebuild":
-        def body(i, state):
-            alive, seeds, gains = state
-            if kind == "bitmap":
-                _, v = kops.fused_select(
-                    alive.astype(jnp.float32), R, interpret=interpret)
-                v = v.astype(jnp.int32)
-            else:
-                v = jnp.argmax(counter_of(alive)).astype(jnp.int32)
-            covered = member(v) & alive
-            gain = covered.sum(dtype=jnp.int32)
-            return alive & ~covered, seeds.at[i].set(v), gains.at[i].set(gain)
-
-        alive, seeds, gains = jax.lax.fori_loop(
-            0, k, body,
-            (valid, jnp.zeros((k,), jnp.int32), jnp.zeros((k,), jnp.int32)))
-    elif method == "decrement":
-        def body(i, state):
-            alive, counter, seeds, gains = state
-            v = jnp.argmax(counter).astype(jnp.int32)
-            covered = member(v) & alive
-            gain = covered.sum(dtype=jnp.int32)
-            counter = counter - counter_of(covered)
-            return (alive & ~covered, counter,
-                    seeds.at[i].set(v), gains.at[i].set(gain))
-
-        alive, _, seeds, gains = jax.lax.fori_loop(
-            0, k, body,
-            (valid, counter_of(valid), jnp.zeros((k,), jnp.int32),
-             jnp.zeros((k,), jnp.int32)))
-    else:
-        raise ValueError(f"unknown method {method}")
-
-    n_valid = jnp.maximum(valid.sum(dtype=jnp.float32), 1.0)
-    return seeds, gains.sum(dtype=jnp.float32) / n_valid, gains
-
-
-def greedy_select(R_or_idx, valid, k: int, *, n: int | None = None,
-                  representation: str = "bitmap", method: str = "rebuild"):
-    """Unified entry point used by the IMM driver."""
-    if representation == "bitmap":
-        return select_dense(R_or_idx, valid, k, method)
-    if representation == "indices":
-        assert n is not None
-        return select_sparse(R_or_idx, valid, n, k, method)
-    raise ValueError(representation)
+    return _on_mesh(
+        mesh, R_idx, valid, k, method,
+        lambda tile: _index_tally(tile, part.block),
+        theta_axes=theta_axes, vertex_axis=vertex_axis, n=n,
+        starts=_starts_for(mesh, vertex_axis, n, part))
 
 
 # ------------------------------------------------- SelectionStrategy API ----
@@ -591,7 +438,9 @@ def greedy_select(R_or_idx, valid, k: int, *, n: int | None = None,
 # A strategy is ``fn(view, k, **opts) -> (seeds, covered_frac, gains)`` where
 # ``view`` is a ``repro.core.store.StoreView`` (duck-typed: .R, .valid, .n).
 # The registry is keyed "<method>-<layout>" with method in
-# {rebuild, decrement} and layout in {dense, sparse, sharded}.
+# {rebuild, decrement} and layout in {dense, sparse, packed, compressed,
+# sharded, sharded-sparse} (packed and compressed registered by
+# ``repro.core.pack.selection``).
 
 SELECTION_STRATEGIES = {}
 
@@ -631,13 +480,14 @@ def _sparse_strategy(method):
 
 def _sharded_strategy(method):
     def run(view, k, *, mesh=None, theta_axes=("data",), vertex_axis=None,
-            partition=None, codec=None, **_):
+            partition=None, codec=None, pallas_interpret=False, **_):
         if mesh is None:
             raise ValueError("sharded selection needs a mesh")
         return select_dense_sharded(
             mesh, view.R, view.valid, k,
             theta_axes=theta_axes, vertex_axis=vertex_axis, method=method,
-            n=view.n, partition=partition, codec=codec)
+            n=view.n, partition=partition, codec=codec,
+            interpret=bool(pallas_interpret))
     return run
 
 
@@ -653,53 +503,11 @@ def _sharded_sparse_strategy(method):
     return run
 
 
-def _fused_dense_strategy(method):
-    def run(view, k, *, pallas_interpret=False, **_):
-        return select_fused(view.R, view.valid, view.n, k, method,
-                            interpret=bool(pallas_interpret))
-    return run
-
-
-def _fused_codec_strategy(method):
-    def run(view, k, *, codec=None, pallas_interpret=False, **_):
-        if codec is None:
-            raise ValueError(
-                "fused packed/compressed selection needs the store codec")
-        return select_fused(view.R, view.valid, view.n, k, method,
-                            codec=codec, interpret=bool(pallas_interpret))
-    return run
-
-
-def _fused_sharded_strategy(method):
-    def run(view, k, *, mesh=None, theta_axes=("data",), vertex_axis=None,
-            partition=None, codec=None, pallas_interpret=False, **_):
-        if mesh is None:
-            raise ValueError("sharded selection needs a mesh")
-        return select_dense_sharded(
-            mesh, view.R, view.valid, k,
-            theta_axes=theta_axes, vertex_axis=vertex_axis, method=method,
-            n=view.n, partition=partition, codec=codec,
-            interpret=bool(pallas_interpret))
-    return run
-
-
 for _m in ("rebuild", "decrement"):
     register_selection(f"{_m}-dense", _dense_strategy(_m))
     register_selection(f"{_m}-sparse", _sparse_strategy(_m))
     register_selection(f"{_m}-sharded", _sharded_strategy(_m))
     register_selection(f"{_m}-sharded-sparse", _sharded_sparse_strategy(_m))
-    # the fused-kernel strategies (PR 10): selection_method="fused-rebuild"
-    # / "fused-decrement" routes every layout's reductions through the
-    # kernels/ops dispatch.  Index-list layouts have no Pallas kernel —
-    # they delegate to the plain strategies so the C4 adaptive switch
-    # under a fused method never dead-ends
-    register_selection(f"fused-{_m}-dense", _fused_dense_strategy(_m))
-    register_selection(f"fused-{_m}-packed", _fused_codec_strategy(_m))
-    register_selection(f"fused-{_m}-compressed", _fused_codec_strategy(_m))
-    register_selection(f"fused-{_m}-sharded", _fused_sharded_strategy(_m))
-    register_selection(f"fused-{_m}-sparse", _sparse_strategy(_m))
-    register_selection(f"fused-{_m}-sharded-sparse",
-                       _sharded_sparse_strategy(_m))
 
 
 # ------------------------------------------- Ripples-faithful baseline ----

@@ -10,13 +10,12 @@ the IM kernels compiled for a TPU v5e by tests/test_tpu_compile.py.
 from repro.kernels import ops, ref
 from repro.kernels.ops import (
     coverage_matvec,
-    fused_select,
     ic_frontier_step,
     fm_interaction,
     flash_attention,
 )
 
 __all__ = [
-    "ops", "ref", "coverage_matvec", "fused_select", "ic_frontier_step",
+    "ops", "ref", "coverage_matvec", "ic_frontier_step",
     "fm_interaction", "flash_attention",
 ]

@@ -34,7 +34,6 @@ from repro.kernels.commit import arena_commit as _commit_pallas
 from repro.kernels.coverage_matvec import coverage_matvec as _coverage_pallas
 from repro.kernels.coverage_rows import coverage_rows as _rows_pallas
 from repro.kernels.coverage_rows import row_view as _row_view_pallas
-from repro.kernels.fused_select import fused_select as _select_pallas
 from repro.kernels.ic_frontier import ic_frontier_step as _frontier_pallas
 from repro.kernels.fm_interaction import fm_interaction as _fm_pallas
 from repro.kernels.flash_attention import flash_attention as _flash_pallas
@@ -80,12 +79,6 @@ def coverage_rows(listed, W, *, interpret=False):
     if _dispatch("coverage_rows", interpret):
         return _rows_pallas(listed, W, interpret=interpret)
     return ref.coverage_rows_ref(listed, W)
-
-
-def fused_select(alive, R, *, interpret=False, **kw):
-    if _dispatch("fused_select", interpret):
-        return _select_pallas(alive, R, interpret=interpret, **kw)
-    return ref.fused_select_ref(alive, R)
 
 
 def ic_frontier_step(frontier, visited, logq, rand, *, interpret=False,

@@ -47,12 +47,6 @@ def coverage_rows_ref(listed, W):
                       bits.astype(jnp.int32))
 
 
-def fused_select_ref(alive, R):
-    """-> (max_count () f32, argmax () int32): one greedy round's reduction."""
-    counter = coverage_matvec_ref(alive, R)
-    return jnp.max(counter), jnp.argmax(counter).astype(jnp.int32)
-
-
 def ic_frontier_ref(frontier, visited, logq, rand):
     """One probabilistic-BFS step in the log-semiring formulation.
 

@@ -69,9 +69,6 @@ KERNEL_COST_MODELS = {
     # masked counter rebuild: (theta,) x (theta, n) mat-vec
     "coverage_matvec": lambda theta, n: (
         2.0 * theta * n, theta * n + 4.0 * theta + 4.0 * n),
-    # same reduction fused with the argmax (outputs are scalars)
-    "fused_select": lambda theta, n: (
-        2.0 * theta * n + n, theta * n + 4.0 * theta),
     # one probabilistic-BFS step: frontier @ logq + activation test
     "ic_frontier_step": lambda B, n: (
         2.0 * B * n * n + 4.0 * B * n,

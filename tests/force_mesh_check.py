@@ -26,9 +26,10 @@ devices).  Asserts the C1 acceptance criteria:
     (`ShardedStore.reset`) equals a fresh one and fills on;
   * the fused sample->write->count chain (``fused_pipeline="auto"``, the
     default) is bitwise identical to an explicitly-unfused run, and the
-    ``fused-rebuild``/``fused-decrement`` selection strategies match
-    their legacy spellings — including on the balanced 2D layout, where
-    pad-column masks and partition offsets must not perturb either.
+    sharded ``rebuild`` and ``decrement`` selections give the
+    single-device selection's seeds, gains and covered_frac — including
+    on the balanced 2D layout, where pad-column masks and partition
+    offsets must not perturb either.
 
 Prints one JSON line on success (consumed by the pytest wrapper).
 """
@@ -43,9 +44,36 @@ import jax
 
 from repro.configs.imm_snap import make_im_mesh, mesh_engine_kwargs
 from repro.core.engine import InfluenceEngine, IMMConfig
+from repro.core.selection import get_selection
 from repro.core.store import BitmapStore, ShardedStore
 from repro.graphs import balance_report, rmat_graph
 from repro.launch.mesh import make_mesh
+from test_imm_core import _numpy_greedy
+
+
+def assert_same_selection(eng, dense, k=5):
+    """Both rules over the meshed engine's arena tiles (``sharded``) and
+    over its tile-local index lists (``sharded-sparse``) give the plain
+    greedy's seeds, gains and covered_frac over the single-device
+    engine's rows."""
+    view = dense.store.view()
+    R, valid = np.asarray(view.R), np.asarray(view.valid)
+    seeds, gains = _numpy_greedy(R, valid, k)
+    frac = float(np.float32(sum(gains)) / np.float32(max(valid.sum(), 1)))
+    st = eng.store
+    # lists as wide as the longest tile-local set: the pow2 width the
+    # engine pads to can pass a narrow balanced tile's column count
+    views = {"sharded": st.view(),
+             "sharded-sparse": st.index_view(st.max_local_size())}
+    for method in ("rebuild", "decrement"):
+        for layout, v in views.items():
+            s, f, g = get_selection(method, layout)(
+                v, k, mesh=eng.mesh, theta_axes=eng.theta_axes,
+                vertex_axis=eng.vertex_axis, partition=st.partition,
+                codec=st.codec)
+            np.testing.assert_array_equal(np.asarray(s), seeds)
+            np.testing.assert_array_equal(np.asarray(g), gains)
+            assert float(f) == frac, (method, layout, float(f), frac)
 
 
 def main(argv=None):
@@ -121,20 +149,15 @@ def main(argv=None):
     np.testing.assert_array_equal(
         sel_dec.seeds, dense.select(5, method="decrement").seeds)
 
-    # --- fused pipeline (PR 10): auto is the default above — prove it
-    # against an explicitly-unfused run, and the fused selection
-    # strategies against their legacy spellings, on this mesh/store cell
+    # --- fused pipeline: auto is the default above — prove it against
+    # an explicitly-unfused run, and both sharded selection rules
+    # against the single-device selection, on this mesh/store cell
     unfused = InfluenceEngine(
         g, dataclasses.replace(cfg, fused_pipeline="off"), **kw)
     r_unf = unfused.run()
     np.testing.assert_array_equal(r_sharded.seeds, r_unf.seeds)
     np.testing.assert_array_equal(r_sharded.counter, r_unf.counter)
-    np.testing.assert_array_equal(
-        sharded.select(5, method="fused-rebuild").seeds, sel_reb.seeds)
-    np.testing.assert_array_equal(
-        sharded.select(5, method="fused-rebuild").gains, sel_reb.gains)
-    np.testing.assert_array_equal(
-        sharded.select(5, method="fused-decrement").seeds, sel_dec.seeds)
+    assert_same_selection(sharded, dense)
 
     # --- layout & schedule invariance: balanced blocks, overlap off -----
     imb = {"equal": 1.0, "balanced": 1.0}
@@ -161,7 +184,7 @@ def main(argv=None):
             g, dataclasses.replace(cfg, partition="balanced",
                                    overlap=False), **kw)
         np.testing.assert_array_equal(r_dense.seeds, both.run().seeds)
-        # fused chain + fused selection on the balanced 2D layout: the
+        # fused chain + sharded selection on the balanced 2D layout: the
         # pad-column masks and partition offsets must not perturb either
         bal_unf = InfluenceEngine(
             g, dataclasses.replace(cfg, partition="balanced",
@@ -169,12 +192,7 @@ def main(argv=None):
         r_bal_unf = bal_unf.run()
         np.testing.assert_array_equal(r_bal.seeds, r_bal_unf.seeds)
         np.testing.assert_array_equal(r_bal.counter, r_bal_unf.counter)
-        np.testing.assert_array_equal(
-            bal.select(5, method="fused-rebuild").seeds,
-            bal.select(5, method="rebuild").seeds)
-        np.testing.assert_array_equal(
-            bal.select(5, method="fused-decrement").seeds,
-            bal.select(5, method="decrement").seeds)
+        assert_same_selection(bal, dense)
     noov = InfluenceEngine(
         g, dataclasses.replace(cfg, overlap=False), **kw)
     r_noov = noov.run()

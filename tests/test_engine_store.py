@@ -284,7 +284,8 @@ def test_custom_sampler_plugs_into_engine():
 
 def test_selection_registry_covers_matrix_and_rejects():
     for method in ("rebuild", "decrement"):
-        for layout in ("dense", "sparse", "sharded"):
+        for layout in ("dense", "sparse", "packed", "compressed", "sharded",
+                       "sharded-sparse"):
             assert callable(get_selection(method, layout))
     with pytest.raises(ValueError):
         get_selection("rebuild", "no-such-layout")

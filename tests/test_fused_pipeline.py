@@ -1,7 +1,8 @@
-"""Fused sample->write->count chain and fused selection vs the legacy
-two-call path: every comparison here is bitwise (exact array equality,
-exact float equality), because the fused pipeline's contract is
-seed-for-seed identity, not statistical agreement."""
+"""Fused sample->write->count chain vs the legacy two-call path, and
+selection over the stores it fills vs the plain greedy: every
+comparison here is bitwise (exact array equality, exact float
+equality), because the fused pipeline's contract is seed-for-seed
+identity, not statistical agreement."""
 import numpy as np
 import jax
 import pytest
@@ -9,6 +10,7 @@ import pytest
 from repro.core.engine import IMMConfig, InfluenceEngine
 from repro.core.selection import get_selection
 from repro.graphs import rmat_graph
+from test_imm_core import _numpy_greedy
 
 N, M, K, BATCH, THETA = 128, 1024, 4, 64, 192
 
@@ -85,7 +87,30 @@ def test_fused_pipeline_off_builds_no_extender():
     assert e._fused is None
 
 
-# ----------------------------------------------------------- fused selection
+# ------------------------------------------------------------- selection
+#
+# The fused chain hands selection a store; the one greedy loop answers
+# over it, in every at-rest form, as the plain greedy does over the same
+# rows.
+
+
+def _assert_plain_greedy(sel, R, valid):
+    """``sel`` is the plain greedy's answer over 0/1 rows ``R``: the
+    same seeds, gains and covered_frac."""
+    seeds, gains = _numpy_greedy(R, valid, K)
+    np.testing.assert_array_equal(np.asarray(sel.seeds), seeds)
+    np.testing.assert_array_equal(np.asarray(sel.gains), gains)
+    assert float(sel.covered_frac) == float(
+        np.float32(sum(gains)) / np.float32(max(valid.sum(), 1)))
+
+
+def _rows(e):
+    """The engine's one-chip arena as 0/1 rows, and its valid mask."""
+    view = e.store.view()
+    codec = getattr(e.store, "codec", None)
+    R = np.asarray(view.R)
+    return (R if codec is None else codec.decode_np(R)), np.asarray(
+        view.valid)
 
 
 @pytest.mark.parametrize("store", ["auto", "packed", "compressed"])
@@ -95,31 +120,26 @@ def test_fused_selection_matches_baseline(store, method):
     e = InfluenceEngine(g, IMMConfig(k=K, batch=BATCH, max_theta=1024,
                                      seed=3, store=store))
     e.extend(THETA)
-    base = e.select(K, method=method)
-    fused = e.select(K, method=f"fused-{method}")
-    np.testing.assert_array_equal(np.asarray(base.seeds),
-                                  np.asarray(fused.seeds))
-    assert float(base.covered_frac) == float(fused.covered_frac)
-    np.testing.assert_array_equal(np.asarray(base.gains),
-                                  np.asarray(fused.gains))
+    _assert_plain_greedy(e.select(K, method=method), *_rows(e))
 
 
 @pytest.mark.parametrize("method", ["rebuild", "decrement"])
 def test_fused_selection_interpret(method):
+    """cfg.pallas_interpret runs the packed arena's counts through the
+    Pallas interpreter: still the plain greedy's answer."""
     g = _graph()
     e = InfluenceEngine(g, IMMConfig(k=K, batch=BATCH, max_theta=1024,
-                                     seed=3, pallas_interpret=True))
+                                     seed=3, store="packed",
+                                     pallas_interpret=True))
     e.extend(THETA)
-    base = e.select(K, method=method)
-    fused = e.select(K, method=f"fused-{method}")
-    np.testing.assert_array_equal(np.asarray(base.seeds),
-                                  np.asarray(fused.seeds))
+    _assert_plain_greedy(e.select(K, method=method), *_rows(e))
 
 
 def test_fused_selection_registry_complete():
-    """Every layout a legacy method serves, the fused spelling serves
-    too — including the sparse delegations the C4 adaptive switch needs."""
-    for method in ("fused-rebuild", "fused-decrement"):
+    """Every layout `InfluenceEngine.select` can choose resolves, for
+    both rules — including the sparse layouts the C4 adaptive switch
+    needs."""
+    for method in ("rebuild", "decrement"):
         for layout in ("dense", "packed", "compressed", "sharded",
                        "sparse", "sharded-sparse"):
             assert callable(get_selection(method, layout))
@@ -163,14 +183,14 @@ def test_fused_sharded_matches_single_device():
 @needs_mesh
 @pytest.mark.parametrize("method", ["rebuild", "decrement"])
 def test_fused_selection_sharded(method):
+    """The sharded strategy on the balanced 2x2 layout answers as the
+    plain greedy does over the single-device engine's rows."""
     from repro.configs.imm_snap import make_im_mesh, mesh_engine_kwargs
     g = _graph()
     mk = mesh_engine_kwargs(make_im_mesh("2x2"))
-    e = InfluenceEngine(g, IMMConfig(k=K, batch=BATCH, max_theta=1024,
-                                     seed=3, partition="balanced"), **mk)
+    cfg = IMMConfig(k=K, batch=BATCH, max_theta=1024, seed=3,
+                    partition="balanced")
+    e, local = InfluenceEngine(g, cfg, **mk), InfluenceEngine(g, cfg)
     e.extend(THETA)
-    base = e.select(K, method=method)
-    fused = e.select(K, method=f"fused-{method}")
-    np.testing.assert_array_equal(np.asarray(base.seeds),
-                                  np.asarray(fused.seeds))
-    assert float(base.covered_frac) == float(fused.covered_frac)
+    local.extend(THETA)
+    _assert_plain_greedy(e.select(K, method=method), *_rows(local))

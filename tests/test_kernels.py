@@ -78,29 +78,6 @@ def test_coverage_rows_sweep(theta, n, listed):
                                   R[rows].sum(axis=0))
 
 
-# ---------------------------------------------------------- fused_select ----
-
-@pytest.mark.parametrize("theta,n", [(64, 100), (513, 300), (256, 2000)])
-def test_fused_select_sweep(theta, n):
-    key = jax.random.PRNGKey(theta + n)
-    R = (jax.random.uniform(key, (theta, n)) < 0.25).astype(jnp.uint8)
-    alive = jax.random.uniform(jax.random.PRNGKey(2), (theta,)) < 0.8
-    mx, idx = ops.fused_select(alive, R, interpret=True)
-    mref, iref = ref.fused_select_ref(alive, R)
-    assert float(mx) == float(mref)
-    # argmax may differ only among ties
-    counter = np.asarray(ref.coverage_matvec_ref(alive, R))
-    assert counter[int(idx)] == float(mref)
-
-
-def test_fused_select_empty_alive():
-    R = jnp.ones((32, 64), jnp.uint8)
-    alive = jnp.zeros((32,), bool)
-    mx, idx = ops.fused_select(alive, R, interpret=True)
-    assert float(mx) == 0.0
-    assert 0 <= int(idx) < 64
-
-
 # ------------------------------------------------------------ ic_frontier ----
 
 @pytest.mark.parametrize("B,n", [(16, 64), (64, 200), (128, 513)])

@@ -56,7 +56,6 @@ def _kernel_case(name, one_chip):
     from repro.kernels.coverage_matvec import coverage_matvec
     from repro.kernels.coverage_rows import (coverage_rows, row_view,
                                              words_per_row)
-    from repro.kernels.fused_select import fused_select
     from repro.kernels.ic_frontier import ic_frontier_step
     from repro.kernels.packed_count import packed_count, token_count
     from repro.kernels.segment_or import segment_or
@@ -71,8 +70,6 @@ def _kernel_case(name, one_chip):
         "coverage_matvec": (coverage_matvec,
                             [S((THETA,), jnp.float32),
                              S((THETA, N), jnp.uint8)]),
-        "fused_select": (fused_select, [S((THETA,), jnp.float32),
-                                        S((THETA, N), jnp.uint8)]),
         "row_view": (row_view, [S((THETA, N), jnp.uint8),
                                 S((THETA,), jnp.bool_)]),
         "coverage_rows": (coverage_rows,
@@ -101,7 +98,7 @@ def _kernel_case(name, one_chip):
 
 @pytest.mark.parametrize("name", [
     "arena_commit_bitmap", "arena_commit_packed", "coverage_matvec",
-    "fused_select", "row_view", "coverage_rows", "packed_count", "token_count_s64", "token_count_s512",
+    "row_view", "coverage_rows", "packed_count", "token_count_s64", "token_count_s512",
     "segment_or", "ic_frontier_n4096"])
 def test_kernel_compiles_for_v5e(one_chip, name):
     import jax
@@ -128,6 +125,26 @@ def test_select_dense_fits_v5e(one_chip, method, monkeypatch):
     total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
              + mem.temp_size_in_bytes)
     assert total < HBM_BYTES, f"select_dense needs {total / 2**30:.2f} GiB"
+
+
+def test_select_packed_fits_v5e(one_chip, monkeypatch):
+    """The greedy loop over a bit-packed arena at com-Amazon's size: its
+    counts run the packed decode-and-count kernel, and the program fits
+    the chip."""
+    import jax
+    import jax.numpy as jnp
+    from repro.core.pack.selection import select_packed
+    from repro.kernels import ops
+
+    monkeypatch.setattr(ops, "_on_tpu", lambda: True)
+    compiled = jax.jit(lambda R, v: select_packed(R, v, N, 50)).lower(
+        _spec(one_chip, (THETA, -(-N // 8)), jnp.uint8),
+        _spec(one_chip, (THETA,), jnp.bool_)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    mem = compiled.memory_analysis()
+    total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+             + mem.temp_size_in_bytes)
+    assert total < HBM_BYTES, f"select_packed needs {total / 2**30:.2f} GiB"
 
 
 def test_select_dense_pick_loop_reads_only_listed_rows(one_chip, monkeypatch):
